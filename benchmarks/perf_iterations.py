@@ -1,7 +1,3 @@
-import os
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """EXPERIMENTS §Perf driver: hypothesis -> change -> measure -> validate.
 
 Three hillclimb cells (chosen from the baseline roofline table):
@@ -20,11 +16,12 @@ perf_results.json (Continue-mode like the dry-run).
 import argparse
 import dataclasses
 import json
+import os
 
 from repro.configs import get_arch, get_shape
 from repro.core.combinator import GlobalKnobs
-from repro.core.plan import uniform_plan
-from repro.launch.dryrun import default_plan, run_cell
+from repro.core.plan import default_plan, uniform_plan
+from repro.launch.dryrun import force_host_devices, run_cell
 from repro.models.context import SegmentClause
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "perf_results.json")
@@ -179,6 +176,7 @@ def run_iterations(cell: str, timeout_s: int = 1700):
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", default=None, choices=[None, "A", "B", "C", "D"])
     ap.add_argument("--timeout", type=int, default=1700)

@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_arch, get_shape
-from repro.launch.dryrun import default_plan
+from repro.core.plan import default_plan
 from repro.models.model import init_cache, model_specs
 from repro.models.params import init_params
 from repro.serve.step import make_decode_step

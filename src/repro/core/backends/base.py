@@ -346,15 +346,17 @@ def executor_to_spec(executor) -> Dict:
         return {"kind": "dryrun", "timeout_s": executor.timeout_s,
                 "hw": dataclasses.asdict(executor.hw), "mesh": mesh_spec}
     if isinstance(executor, WallClockExecutor):
-        return {"kind": "wallclock", "timeout_s": executor.timeout_s,
-                "repeats": executor.repeats, "mesh": mesh_spec}
+        raise ValueError(
+            "WallClockExecutor times on the devices of the process that "
+            "holds them; score it in-process (backend='thread' or "
+            "'sequential'), not on the process/remote backends")
     if isinstance(executor, SleepExecutor):
         return {"kind": "sleep", "sleep_s": executor.sleep_s,
                 "timeout_s": executor.timeout_s}
     if isinstance(executor, CrashExecutor):
         return {"kind": "crash", "timeout_s": executor.timeout_s}
     raise TypeError(f"no wire spec for executor {type(executor).__name__} "
-                    f"(process backend supports dryrun/wallclock)")
+                    f"(process backend supports dryrun)")
 
 
 def executor_from_spec(spec: Dict, *, allow_test: bool = False):
@@ -371,7 +373,7 @@ def executor_from_spec(spec: Dict, *, allow_test: bool = False):
     """
     from repro.core.cost_model import Hardware, V5E
     from repro.core.executor import (CrashExecutor, DryRunExecutor,
-                                     SleepExecutor, WallClockExecutor)
+                                     SleepExecutor)
     from repro.core.meshspec import cached_mesh
     kind = spec["kind"]
     mesh = cached_mesh(MeshSpec.from_json(spec["mesh"])) \
@@ -379,9 +381,6 @@ def executor_from_spec(spec: Dict, *, allow_test: bool = False):
     if kind == "dryrun":
         hw = Hardware(**spec["hw"]) if spec.get("hw") else V5E
         return DryRunExecutor(mesh, hw=hw, timeout_s=spec.get("timeout_s"))
-    if kind == "wallclock":
-        return WallClockExecutor(mesh, repeats=spec.get("repeats", 5),
-                                 timeout_s=spec.get("timeout_s"))
     if allow_test and kind == "sleep":
         return SleepExecutor(sleep_s=spec.get("sleep_s", 3600.0),
                              timeout_s=spec.get("timeout_s"))

@@ -33,6 +33,7 @@ from __future__ import annotations
 import logging
 import multiprocessing as mp
 import multiprocessing.connection
+import os
 import time
 from collections import deque
 from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
@@ -123,7 +124,15 @@ def _score_one(executor, cfg, shape, spec: JobSpec, cache, shape_key: str,
 
 def _worker_main(conn, init: Dict):
     """Worker process entry point: build cfg/shape/executor once (warm
-    reuse), then serve JobSpec JSON until a ``None`` shutdown message."""
+    reuse), then serve JobSpec JSON until a ``None`` shutdown message.
+
+    Workers score analytically and never claim an accelerator: on a TPU
+    host the chip belongs to the parent, so the worker (and anything it
+    starts) is pinned to the CPU backend before any backend initializes.
+    """
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
     from repro.configs.registry import arch_from_spec, shape_from_spec
     from repro.core.db import ScoreCacheReader
     cfg = arch_from_spec(init["arch"])

@@ -88,18 +88,6 @@ def deadline(seconds: Optional[int]):
         signal.signal(signal.SIGALRM, old)
 
 
-@contextmanager
-def _mesh_scope(mesh):
-    """jax.set_mesh when available (jax >= 0.6), else the Mesh context
-    manager — same effect for lowering under a mesh."""
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield
-    else:
-        with mesh:
-            yield
-
-
 def lower_and_compile(fn, args, shardings, mesh, donate_argnums=()):
     kw = {}
     if mesh is not None and shardings is not None:
@@ -108,7 +96,7 @@ def lower_and_compile(fn, args, shardings, mesh, donate_argnums=()):
         kw["donate_argnums"] = tuple(donate_argnums)
     jitted = jax.jit(fn, **kw)
     if mesh is not None:
-        with _mesh_scope(mesh):
+        with jax.set_mesh(mesh):
             lowered = jitted.lower(*args)
             compiled = lowered.compile()
     else:
@@ -133,8 +121,6 @@ def analyze_compiled(lowered, compiled, n_chips: int,
     res = analyze_hlo(hlo)
     f_pd, b_pd, c_pd = res["flops"], res["bytes"], res["collective"]
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):       # jax < 0.5: one dict per device
-        ca = ca[0] if ca else {}
     mem = {}
     try:
         ma = compiled.memory_analysis()
@@ -247,9 +233,7 @@ class WallClockExecutor:
             try:
                 fn, args, shardings = segment_program(
                     cfg, shape, seg, combo, mesh, knobs=knobs)
-                concrete = jax.tree.map(
-                    lambda s: _materialize(s), args,
-                    is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct))
+                concrete = _materialize(args, shardings)
                 lowered, compiled = lower_and_compile(
                     fn, concrete, shardings, mesh)
                 out = compiled(*concrete)
@@ -445,9 +429,24 @@ class ParallelSweepRunner:
                     yield fut.result()
 
 
-def _materialize(sds: jax.ShapeDtypeStruct):
-    if np.issubdtype(sds.dtype, np.integer):
-        return jax.numpy.zeros(sds.shape, sds.dtype)
-    key = jax.random.key(42)
-    return (jax.random.normal(key, sds.shape, "float32") * 0.02
-            ).astype(sds.dtype)
+def _stand_in(shape, dtype):
+    if np.issubdtype(dtype, np.integer):
+        return jax.numpy.zeros(shape, dtype)
+    return (jax.random.normal(jax.random.key(42), shape, "float32") * 0.02
+            ).astype(dtype)
+
+
+def _materialize(args, shardings):
+    """Concrete stand-ins for abstract ``args``, each leaf built directly
+    under its sharding (``shardings`` is a prefix tree of ``args``; None
+    = default device), so a sharded program never starts from a whole
+    copy on one device."""
+    def make(sds, sharding):
+        return jax.jit(_stand_in, static_argnums=(0, 1),
+                       out_shardings=sharding)(tuple(sds.shape),
+                                               np.dtype(sds.dtype))
+
+    return jax.tree.map(
+        lambda sh, sub: jax.tree.map(lambda s: make(s, sh), sub),
+        shardings, args,
+        is_leaf=lambda x: x is None or isinstance(x, jax.sharding.Sharding))

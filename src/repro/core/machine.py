@@ -176,7 +176,6 @@ def _collective_points(n_devices: int, shard_bytes: int,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.core.meshspec import MeshSpec
-    from repro.runtime.sharding import shard_map_compat
 
     out: Dict[str, Dict[str, float]] = {}
     if n_devices < 2:
@@ -195,8 +194,8 @@ def _collective_points(n_devices: int, shard_bytes: int,
     }
     for kind, (body, in_spec, out_spec, conv_bytes) in probes.items():
         try:
-            f = shard_map_compat(body, mesh=mesh, in_specs=in_spec,
-                                 out_specs=out_spec)
+            f = jax.shard_map(body, mesh=mesh, in_specs=in_spec,
+                              out_specs=out_spec, check_vma=False)
             t = _time_best(jax.jit(f), x, repeats=repeats)
         except Exception as e:
             log.debug("collective probe %s failed: %s", kind, e)

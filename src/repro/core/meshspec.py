@@ -140,7 +140,7 @@ class MeshSpec:
         import jax
         return [d for d in jax.devices()
                 if not self.device_kind
-                or getattr(d, "platform", "") == self.device_kind]
+                or d.device_kind == self.device_kind]
 
     def check_local(self):
         """Raise :class:`MeshUnsatisfiable` unless this host can

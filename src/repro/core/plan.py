@@ -11,7 +11,7 @@ import logging
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
-from repro.configs.base import ArchConfig
+from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.combinator import Combination, GlobalKnobs
 from repro.core.meshspec import MeshSpec
 from repro.core.providers import get_provider
@@ -84,6 +84,31 @@ def uniform_plan(cfg: ArchConfig, provider: str,
                 knobs or GlobalKnobs())
 
 
+def default_plan(cfg: ArchConfig, shape: ShapeConfig) -> Plan:
+    """The a-priori 'single best compiler' baseline plan per cell
+    (what a practitioner would pick without ComParX's sweep)."""
+    if shape.kind == "train":
+        clause = SegmentClause(remat="dots", kernel="xla")
+        knobs = GlobalKnobs(microbatches=1, donate=True,
+                            opt_state_dtype="bfloat16" if cfg.is_moe
+                            else "float32")
+        if cfg.is_moe:
+            return uniform_plan(
+                cfg, "expert_par",
+                frozenset({"tp_attention", "fsdp_dense", "2d_experts"}),
+                clause, knobs)
+        return uniform_plan(cfg, "hybrid2d", frozenset({"shard_vocab"}),
+                            clause, knobs)
+    clause = SegmentClause(remat="none", kernel="xla")
+    if cfg.is_moe:
+        return uniform_plan(
+            cfg, "expert_par",
+            frozenset({"tp_attention", "fsdp_dense", "2d_experts"}),
+            clause)
+    return uniform_plan(cfg, "tensor_par", frozenset({"shard_vocab"}),
+                        clause)
+
+
 def dp_shards(mesh) -> int:
     """Number of data-parallel shards (pod x data axes)."""
     if mesh is None:
@@ -92,8 +117,8 @@ def dp_shards(mesh) -> int:
     return sizes.get("pod", 1) * sizes.get("data", 1)
 
 
-def build_contexts(cfg: ArchConfig, mesh, plan: Plan,
-                   *, interpret: bool = True) -> Dict[str, ModelContext]:
+def build_contexts(cfg: ArchConfig, mesh,
+                   plan: Plan) -> Dict[str, ModelContext]:
     """Apply a plan: per-segment ModelContext with provider rules.
 
     A plan missing a segment (e.g. fused for a smaller config) gets that
@@ -118,7 +143,7 @@ def build_contexts(cfg: ArchConfig, mesh, plan: Plan,
         mapping = provider.mapping(cfg, axis_sizes, combo.flags, seg)
         ctxs[seg.name] = ModelContext(
             rules=Rules(mapping, mesh), clause=combo.clause,
-            moe_groups=groups, interpret=interpret)
+            moe_groups=groups)
     if substituted:
         plan.meta.setdefault("substituted_segments", {}).update(substituted)
     return ctxs

@@ -30,18 +30,17 @@ from repro.models.params import abstract_params, param_pspecs
 from repro.runtime.sharding import Rules
 
 
-def _ctx_for(cfg, mesh, combo: Combination, seg: Segment,
-             interpret: bool = True) -> ModelContext:
+def _ctx_for(cfg, mesh, combo: Combination, seg: Segment) -> ModelContext:
     axis_sizes = dict(zip(mesh.axis_names, mesh.devices.shape)) \
         if mesh is not None else {}
     mapping = get_provider(combo.provider).mapping(
         cfg, axis_sizes, combo.flags, seg)
     return ModelContext(rules=Rules(mapping, mesh), clause=combo.clause,
-                        moe_groups=dp_shards(mesh), interpret=interpret)
+                        moe_groups=dp_shards(mesh))
 
 
 def segment_program(cfg: ArchConfig, shape: ShapeConfig, seg: Segment,
-                    combo: Combination, mesh, *, interpret: bool = True,
+                    combo: Combination, mesh, *,
                     knobs: Optional[GlobalKnobs] = None
                     ) -> Tuple[Callable, Tuple, Dict]:
     """Build (fn, abstract_args, arg_shardings) for one segment.
@@ -54,7 +53,7 @@ def segment_program(cfg: ArchConfig, shape: ShapeConfig, seg: Segment,
     knob fields in ``Segment.relevant_knob_fields`` reach the program;
     inference shapes ignore knobs entirely.
     """
-    ctx = _ctx_for(cfg, mesh, combo, seg, interpret)
+    ctx = _ctx_for(cfg, mesh, combo, seg)
     specs = model_specs(cfg)
     B, S = shape.global_batch, shape.seq_len
     i32 = jnp.dtype("int32")

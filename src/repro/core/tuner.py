@@ -458,6 +458,14 @@ class ComParTuner:
         if fallback is not None and backend != "remote":
             raise ValueError("fallback= is the remote backend's degraded "
                              "mode; it needs remote_url/backend='remote'")
+        if backend in ("process", "remote") and isinstance(
+                self.executor, WallClockExecutor):
+            # a chip belongs to one process: a worker or a scoring server
+            # would time on devices this process holds, or on another host
+            raise ValueError(
+                f"backend={backend!r} scores outside this process; a "
+                "wallclock sweep times on this process's devices, so use "
+                "backend='thread' or 'sequential'")
         if workers > 1 and not getattr(self.executor, "parallel_safe", True):
             log.warning("workers=%d -> 1: %s timings would contend on the "
                         "device", workers, type(self.executor).__name__)

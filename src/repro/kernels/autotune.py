@@ -144,8 +144,7 @@ def _clamp_chunk(chunk: int, S: int) -> int:
     return c
 
 
-def _op_program(op: str, fields: Dict[str, object], cfg, shape,
-                interpret: bool = True):
+def _op_program(op: str, fields: Dict[str, object], cfg, shape):
     import jax
     import jax.numpy as jnp
 
@@ -165,8 +164,7 @@ def _op_program(op: str, fields: Dict[str, object], cfg, shape,
             def fn(q, k, v):
                 return flash_attention(q, k, v, causal=True,
                                        window=cfg.window_size,
-                                       block_q=bq, block_k=bk,
-                                       interpret=interpret)
+                                       block_q=bq, block_k=bk)
         else:
             from repro.models.attention import chunked_attention
 
@@ -185,8 +183,7 @@ def _op_program(op: str, fields: Dict[str, object], cfg, shape,
             from repro.kernels.ops import flash_decode
 
             def fn(q, k, v):
-                return flash_decode(q, k, v, pos, block_k=bk,
-                                    interpret=interpret)
+                return flash_decode(q, k, v, pos, block_k=bk)
         else:
             from repro.models.attention import decode_attention
 
@@ -206,8 +203,7 @@ def _op_program(op: str, fields: Dict[str, object], cfg, shape,
             from repro.kernels.ops import mlstm_chunkwise
 
             def fn(q, k, v, li, lf):
-                return mlstm_chunkwise(q, k, v, li, lf, chunk=c,
-                                       interpret=interpret)
+                return mlstm_chunkwise(q, k, v, li, lf, chunk=c)
         else:
             from repro.models.xlstm import mlstm_chunk
 
@@ -235,7 +231,7 @@ def _op_program(op: str, fields: Dict[str, object], cfg, shape,
             c = _clamp_chunk(fields["mlstm_chunk"], S)
 
             def fn(log_a, b):
-                return rglru(log_a, b, chunk=c, interpret=interpret)
+                return rglru(log_a, b, chunk=c)
         else:
             from repro.models.rglru import rglru_scan
 

@@ -11,11 +11,14 @@ applied in-kernel; fully-masked blocks are neutralized multiplicatively
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.backend import interpret_mode
 
 NEG_INF = -1e30
 
@@ -65,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
                         block_q: int = 512, block_k: int = 1024,
-                        interpret: bool = True):
+                        interpret: Optional[bool] = None):
     """q: (B,H,Sq,D); k/v: (B,KV,Sk,D) -> (B,H,Sq,D)."""
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -94,5 +97,5 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(q, k, v)

@@ -2,19 +2,24 @@
 
 Layout: q (B, H, D); k/v (B, KV, Smax, D) head-major.  Grid (B, H, nk)
 streams the KV cache in ``block_k`` tiles, carrying online-softmax state in
-VMEM scratch.  The token position ``pos`` arrives as a (1, 1) int32 array
-(read from VMEM) and masks out not-yet-written cache slots.  Emits the attention
-output and, optionally, per-(head) LSE so sequence-sharded shards can be
-combined with a single ``psum`` (see ``repro.serve``).
+VMEM scratch.  The token position ``pos`` arrives as a (1, 1) int32 array in
+SMEM and masks out not-yet-written cache slots.  Outputs are laid out
+(B, H, 1, D) and (B, H, 1, 1) so every block's last two dims are the full
+array dims, as the TPU tiling rule requires.  Emits the attention output
+and, optionally, per-(head) LSE so sequence-sharded shards can be combined
+with a single ``psum`` (see ``repro.serve``).
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.runtime.backend import interpret_mode
 
 NEG_INF = -1e30
 
@@ -51,12 +56,12 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ik == nk - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)[0]
-        lse_ref[0, 0] = (m_ref[0, 0] + jnp.log(l[0, 0]))
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_decode_fwd(q, k, v, pos, *, block_k: int = 1024,
-                     interpret: bool = True, return_lse: bool = False):
+                     interpret: Optional[bool] = None, return_lse: bool = False):
     """q: (B,H,D); k/v: (B,KV,Smax,D); pos scalar int32 -> (B,H,D)."""
     B, H, D = q.shape
     KV, Smax = k.shape[1], k.shape[2]
@@ -72,24 +77,25 @@ def flash_decode_fwd(q, k, v, pos, *, block_k: int = 1024,
         kern,
         grid=(B, H, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ik: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h // G, ik, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik: (b, h // G, ik, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, D), lambda b, h, ik: (b, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ik: (b, h)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, ik: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1), lambda b, h, ik: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+            jax.ShapeDtypeStruct((B, H, 1, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(pos_arr, q4, k, v)
+    out, lse = out[:, :, 0], lse[:, :, 0, 0]
     return (out, lse) if return_lse else out

@@ -2,10 +2,12 @@
 
 Every op is differentiable via ``jax.custom_vjp``: forward runs the Pallas
 kernel, backward runs the vjp of the pure-jnp reference (chunked where
-memory matters).  On a real TPU deployment the backward would also be a
-Pallas kernel; on this CPU container kernels execute in interpret mode.
+memory matters).  ``interpret=None`` runs the kernels compiled on a TPU
+backend and interpreted elsewhere (``repro.runtime.backend``).
 """
 from __future__ import annotations
+
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,7 +22,7 @@ from repro.kernels.rmsnorm import rmsnorm_fwd
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 512, block_k: int = 1024,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """Model layout: q (B,S,H,D); k/v (B,S,KV,D) -> (B,S,H,D)."""
 
     def _run(q, k, v):
@@ -56,7 +58,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 def flash_decode(q, k_cache, v_cache, pos, *, block_k: int = 1024,
-                 interpret: bool = True, return_lse: bool = False):
+                 interpret: Optional[bool] = None, return_lse: bool = False):
     """Model layout: q (B,H,D); caches (B,Smax,KV,D)."""
     kh = jnp.moveaxis(k_cache, 2, 1)                   # (B,KV,Smax,D)
     vh = jnp.moveaxis(v_cache, 2, 1)
@@ -64,7 +66,7 @@ def flash_decode(q, k_cache, v_cache, pos, *, block_k: int = 1024,
                             interpret=interpret, return_lse=return_lse)
 
 
-def rglru(log_a, b, *, chunk: int = 256, interpret: bool = True):
+def rglru(log_a, b, *, chunk: int = 256, interpret: Optional[bool] = None):
     """log_a, b: (B,S,dr) -> h (B,S,dr) f32."""
 
     @jax.custom_vjp
@@ -84,7 +86,7 @@ def rglru(log_a, b, *, chunk: int = 256, interpret: bool = True):
 
 
 def mlstm_chunkwise(q, k, v, li, lf, *, chunk: int = 256,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q,k,v: (B,H,S,dh) f32 (q pre-scaled); li,lf: (B,H,S) -> (B,H,S,dh)."""
 
     def _ref(q, k, v, li, lf):
@@ -123,7 +125,7 @@ def mlstm_chunkwise(q, k, v, li, lf, *, chunk: int = 256,
     return op(q, k, v, li, lf)
 
 
-def rmsnorm(x, scale, *, eps: float = 1e-6, interpret: bool = True):
+def rmsnorm(x, scale, *, eps: float = 1e-6, interpret: Optional[bool] = None):
     """x: (..., d) -> fused rmsnorm."""
     shp = x.shape
     x2 = x.reshape(-1, shp[-1])

@@ -2,10 +2,13 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.runtime.backend import interpret_mode
 
 
 def _kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -16,7 +19,7 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
-                interpret: bool = True):
+                interpret: Optional[bool] = None):
     """x: (N, d); scale: (d,) -> (N, d)."""
     N, d = x.shape
     br = min(block_rows, N)
@@ -32,5 +35,5 @@ def rmsnorm_fwd(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
         ],
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((N, d), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, scale[None, :])

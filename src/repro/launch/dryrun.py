@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 This is the proof that the distribution config is coherent without real
@@ -17,6 +14,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from typing import Dict, Optional
@@ -25,36 +23,19 @@ import jax
 
 from repro.configs import ARCHS, SHAPES, get_arch, get_shape, shape_applies
 from repro.configs.base import ArchConfig, ShapeConfig
-from repro.core.combinator import GlobalKnobs
-from repro.core.executor import analyze_compiled, deadline, CombinationFailed
-from repro.core.plan import Plan, uniform_plan
+from repro.core.executor import analyze_compiled, deadline
+from repro.core.plan import Plan, default_plan
 from repro.launch.mesh import make_production_mesh, mesh_chips
-from repro.models.context import SegmentClause
 
 
-def default_plan(cfg: ArchConfig, shape: ShapeConfig) -> Plan:
-    """The a-priori 'single best compiler' baseline plan per cell
-    (what a practitioner would pick without ComParX's sweep)."""
-    if shape.kind == "train":
-        clause = SegmentClause(remat="dots", kernel="xla")
-        knobs = GlobalKnobs(microbatches=1, donate=True,
-                            opt_state_dtype="bfloat16" if cfg.is_moe
-                            else "float32")
-        if cfg.is_moe:
-            return uniform_plan(
-                cfg, "expert_par",
-                frozenset({"tp_attention", "fsdp_dense", "2d_experts"}),
-                clause, knobs)
-        return uniform_plan(cfg, "hybrid2d", frozenset({"shard_vocab"}),
-                            clause, knobs)
-    clause = SegmentClause(remat="none", kernel="xla")
-    if cfg.is_moe:
-        return uniform_plan(
-            cfg, "expert_par",
-            frozenset({"tp_attention", "fsdp_dense", "2d_experts"}),
-            clause)
-    return uniform_plan(cfg, "tensor_par", frozenset({"shard_vocab"}),
-                        clause)
+def force_host_devices():
+    """Give the CPU backend 512 placeholder devices for the production
+    meshes.  Appends to ``XLA_FLAGS``; takes effect only before JAX
+    initializes its backends, so entry points call it first thing."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count=512").strip()
 
 
 def input_specs(arch: str, shape_name: Optional[str] = None) -> Dict:
@@ -82,10 +63,9 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                                   make_decode_step, make_prefill,
                                   prefill_input_specs)
 
-    from repro.core.executor import _mesh_scope
-    with _mesh_scope(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
-            step, sh = make_train_step(cfg, mesh, plan, interpret=False)
+            step, sh = make_train_step(cfg, mesh, plan)
             params, opt = abstract_train_state(cfg, plan)
             batch = input_specs(cfg.name, shape.name)["batch"]
             jitted = jax.jit(
@@ -95,14 +75,14 @@ def lower_cell(cfg: ArchConfig, shape: ShapeConfig, mesh,
                 donate_argnums=(0, 1) if plan.knobs.donate else ())
             lowered = jitted.lower(params, opt, batch)
         elif shape.kind == "prefill":
-            fn, sh = make_prefill(cfg, mesh, plan, interpret=False)
+            fn, sh = make_prefill(cfg, mesh, plan)
             from repro.models.params import abstract_params
             params = abstract_params(model_specs(cfg))
             batch = prefill_input_specs(cfg, shape)
             jitted = jax.jit(fn, in_shardings=(sh["params"], None))
             lowered = jitted.lower(params, batch)
         else:
-            fn, sh = make_decode_step(cfg, mesh, plan, interpret=False)
+            fn, sh = make_decode_step(cfg, mesh, plan)
             params = abstract_params(model_specs(cfg))
             caches = cache_specs(cfg, shape.global_batch, shape.seq_len)
             csh = cache_shardings(cfg, shape, mesh, plan)
@@ -159,6 +139,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
 
 
 def main():
+    force_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -9,18 +9,12 @@ the property that scales the design past 1000 nodes.
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: meshes are Auto-typed
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mesh(shape, axes):
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

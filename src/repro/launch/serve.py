@@ -19,7 +19,8 @@ import argparse
 import numpy as np
 
 from repro.configs import get_arch
-from repro.core.plan import Plan
+from repro.core.plan import Plan, default_plan
+from repro.runtime.backend import enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 from repro.serve.registry import PlanRegistry, serving_shape
 
@@ -51,7 +52,6 @@ def resolve_plan(cfg, shape, *, plan_path=None, registry_db=None):
                 f"(python -m repro.serve.registry)")
         src = "registry" if entry.exact else f"registry~{entry.shape}"
         return entry.plan, src
-    from repro.launch.dryrun import default_plan
     return default_plan(cfg, shape), "default"
 
 
@@ -100,4 +100,5 @@ def serve(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     serve()

@@ -22,6 +22,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import tempfile
 import time
 
 import jax
@@ -30,10 +31,10 @@ import numpy as np
 from repro.checkpoint.store import CheckpointStore
 from repro.configs import get_arch, get_shape
 from repro.configs.base import ShapeConfig
-from repro.core.plan import Plan
+from repro.core.plan import Plan, default_plan
 from repro.data.pipeline import SyntheticLM
-from repro.launch.dryrun import default_plan
 from repro.launch.mesh import make_test_mesh
+from repro.runtime.backend import enable_compile_cache
 from repro.train.step import init_train_state, jit_train_step
 
 
@@ -92,10 +93,12 @@ def train(argv=None):
     step_fn, shardings = jit_train_step(cfg, mesh, plan,
                                         peak_lr=args.lr,
                                         warmup=args.warmup)
-    params, opt = init_train_state(cfg, plan, jax.random.key(args.seed))
+    params, opt = init_train_state(cfg, plan, jax.random.key(args.seed),
+                                   shardings if mesh is not None else None)
     data = SyntheticLM(cfg, shape, seed=args.seed)
     store = CheckpointStore(
-        args.ckpt_dir or f"/tmp/repro_ckpt_{cfg.name}", keep=3)
+        args.ckpt_dir or os.path.join(tempfile.gettempdir(),
+                                      f"repro_ckpt_{cfg.name}"), keep=3)
     start = 0
     if args.resume == "auto" and store.latest_step() is not None:
         start, state, extra = store.restore(
@@ -134,4 +137,5 @@ def train(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     train()

@@ -210,8 +210,6 @@ def attn_decode_shardmap(q, k, v, cache, pos, ctx: ModelContext):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.runtime.sharding import shard_map_compat
-
     mesh = ctx.rules.mesh
     axis_sizes = ctx.rules.axis_sizes
     tp = axis_sizes["model"]
@@ -256,12 +254,12 @@ def attn_decode_shardmap(q, k, v, cache, pos, ctx: ModelContext):
         return o.reshape(q.shape[0], H, D).astype(q.dtype), ck, cv
 
     cache_spec = P(b_ax, "model", None, None)
-    o, ck, cv = shard_map_compat(
+    o, ck, cv = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(b_ax, None, None), P(b_ax, None, None),
                   P(b_ax, None, None), cache_spec, cache_spec, P()),
         out_specs=(P(b_ax, None, None), cache_spec, cache_spec),
-        check=False,
+        check_vma=False,
     )(q, k, v, cache["k"], cache["v"], pos)
     return o, {"k": ck, "v": cv}
 

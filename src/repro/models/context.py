@@ -7,7 +7,7 @@ Combinator sweeps and the Optimal Plan Generator fuses.
 from __future__ import annotations
 
 from dataclasses import dataclass, field, replace
-from typing import Tuple
+from typing import Optional, Tuple
 
 from repro.runtime.sharding import Rules
 
@@ -38,7 +38,9 @@ class ModelContext:
     rules: Rules = field(default_factory=Rules.null)
     clause: SegmentClause = SegmentClause()
     moe_groups: int = 1          # GShard-style dispatch groups
-    interpret: bool = True       # pallas interpret mode (CPU container)
+    #: pallas interpret mode; None = compiled on a TPU backend,
+    #: interpreted elsewhere (repro.runtime.backend.interpret_mode)
+    interpret: Optional[bool] = None
     decode: bool = False
 
     def with_(self, **kw) -> "ModelContext":

@@ -156,7 +156,6 @@ def moe_apply_sorted(p, x, cfg: ArchConfig, ctx: ModelContext):
 def moe_apply_a2a(p, x, cfg: ArchConfig, ctx: ModelContext):
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro.runtime.sharding import shard_map_compat
 
     mesh = ctx.rules.mesh
     axis_sizes = ctx.rules.axis_sizes
@@ -217,17 +216,17 @@ def moe_apply_a2a(p, x, cfg: ArchConfig, ctx: ModelContext):
 
     wg = p.get("wg")
     router = p["router"].astype(jnp.float32)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(x_spec, r_spec, w_spec_i, w_spec_i if wg is not None
                   else P(), w_spec_i),
         out_specs=out_spec,
-        check=False)
+        check_vma=False)
     if wg is None:
-        fn_out = shard_map_compat(
+        fn_out = jax.shard_map(
             lambda xl, r, wi, wo: local_moe(xl, r, wi, None, wo),
             mesh=mesh, in_specs=(x_spec, r_spec, w_spec_i, w_spec_i),
-            out_specs=out_spec, check=False)
+            out_specs=out_spec, check_vma=False)
         y = fn_out(x, router, p["wi"], p["wo"])
     else:
         y = fn(x, router, p["wi"], wg, p["wo"])

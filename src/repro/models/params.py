@@ -15,6 +15,7 @@ conv, layers`` (``layers`` is the scan-stack dim and is never sharded).
 from __future__ import annotations
 
 import dataclasses
+import zlib
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -51,10 +52,10 @@ def is_spec(x) -> bool:
 
 
 def _leaf_key(root_key, path) -> jax.Array:
-    # deterministic per-leaf key derived from the flattened path string
+    # per-leaf key from the flattened path string; crc32, not hash(), so
+    # the same seed gives the same weights in every process
     name = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
-    h = hash(name) % (2 ** 31 - 1)
-    return jax.random.fold_in(root_key, h)
+    return jax.random.fold_in(root_key, zlib.crc32(name.encode()) >> 1)
 
 
 def init_params(specs, key):

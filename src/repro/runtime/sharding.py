@@ -15,20 +15,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 Candidate = Union[None, str, Tuple[str, ...]]
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions: the replication-check kwarg
-    is ``check_vma`` from jax 0.6 and ``check_rep`` before (where the
-    function lives in ``jax.experimental.shard_map``)."""
-    try:
-        from jax import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=check)
-
-
 def _as_candidates(v) -> List[Candidate]:
     """Normalize a mapping value into an ordered candidate list."""
     if isinstance(v, list):

@@ -151,8 +151,7 @@ class ServeEngine:
     """
 
     def __init__(self, cfg: ArchConfig, plan: Plan, *, capacity: int = 4,
-                 cache_len: int = 64, mesh=None, params=None, seed: int = 0,
-                 interpret: bool = True):
+                 cache_len: int = 64, mesh=None, params=None, seed: int = 0):
         if cfg.is_moe:
             log.warning(
                 "%s is MoE: expert routing mixes rows across the batch, "
@@ -161,11 +160,11 @@ class ServeEngine:
                 cfg.name)
         self.cfg, self.plan, self.mesh = cfg, plan, mesh
         self.capacity, self.cache_len = int(capacity), int(cache_len)
-        step_fn, _ = make_decode_step(cfg, mesh, plan, interpret=interpret)
+        step_fn, _ = make_decode_step(cfg, mesh, plan)
         self._step = jax.jit(step_fn, donate_argnums=(1,))
         # one jit object; retraces per distinct prompt length
         self._prefill = jax.jit(
-            make_prefill_cache(cfg, mesh, plan, interpret=interpret),
+            make_prefill_cache(cfg, mesh, plan),
             donate_argnums=(1,))
         self.params = params if params is not None else init_params(
             model_specs(cfg), jax.random.key(seed))

@@ -78,11 +78,10 @@ def decode_input_specs(cfg: ArchConfig, shape: ShapeConfig):
             "pos": jax.ShapeDtypeStruct((), jnp.dtype("int32"))}
 
 
-def make_decode_step(cfg: ArchConfig, mesh, plan: Plan, *,
-                     interpret: bool = True, greedy: bool = True):
+def make_decode_step(cfg: ArchConfig, mesh, plan: Plan):
     """Returns (serve_step, shardings). serve_step:
     (params, caches, tokens, pos) -> (next_tokens, logits, new_caches)."""
-    ctxs = build_contexts(cfg, mesh, plan, interpret=interpret)
+    ctxs = build_contexts(cfg, mesh, plan)
 
     def serve_step(params, caches, tokens, pos):
         logits, new_caches = decode_step(params, caches, tokens, pos,
@@ -95,10 +94,9 @@ def make_decode_step(cfg: ArchConfig, mesh, plan: Plan, *,
     return serve_step, shardings
 
 
-def make_prefill(cfg: ArchConfig, mesh, plan: Plan, *,
-                 interpret: bool = True):
+def make_prefill(cfg: ArchConfig, mesh, plan: Plan):
     """Full-sequence forward (prefill compute shape). Returns logits."""
-    ctxs = build_contexts(cfg, mesh, plan, interpret=interpret)
+    ctxs = build_contexts(cfg, mesh, plan)
 
     def prefill(params, batch):
         logits, _ = forward(params, batch, cfg, ctxs)
@@ -108,8 +106,7 @@ def make_prefill(cfg: ArchConfig, mesh, plan: Plan, *,
     return prefill, {"params": param_shardings(cfg, mesh, plan)}
 
 
-def make_prefill_cache(cfg: ArchConfig, mesh, plan: Plan, *,
-                       interpret: bool = True):
+def make_prefill_cache(cfg: ArchConfig, mesh, plan: Plan):
     """The serving engine's prefill segment: consume a prompt into a
     decode cache.
 
@@ -125,7 +122,7 @@ def make_prefill_cache(cfg: ArchConfig, mesh, plan: Plan, *,
     last_logits (B,V) f32, new_caches)`` where ``prompt`` is (B, P)
     int32 and ``caches`` a fresh ``init_cache`` pytree.
     """
-    ctxs = build_contexts(cfg, mesh, plan, interpret=interpret)
+    ctxs = build_contexts(cfg, mesh, plan)
 
     def prefill(params, caches, prompt):
         P = prompt.shape[1]

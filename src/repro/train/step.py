@@ -73,12 +73,11 @@ def batch_shardings(cfg: ArchConfig, shape: ShapeConfig, mesh, plan: Plan):
 
 
 def make_train_step(cfg: ArchConfig, mesh, plan: Plan, *,
-                    interpret: bool = True,
                     peak_lr: float = 3e-4, warmup: int = 100,
                     total_steps: int = 10000,
                     weight_decay: float = 0.1, clip_norm: float = 1.0):
     """Returns (train_step_fn, shardings dict)."""
-    ctxs = build_contexts(cfg, mesh, plan, interpret=interpret)
+    ctxs = build_contexts(cfg, mesh, plan)
     mb = plan.knobs.microbatches
 
     def loss_fn(params, batch):
@@ -137,18 +136,24 @@ def abstract_train_state(cfg: ArchConfig, plan: Plan):
     return params, opt
 
 
-def init_train_state(cfg: ArchConfig, plan: Plan, key):
+def init_train_state(cfg: ArchConfig, plan: Plan, key, shardings=None):
+    """Random params + fresh optimizer state.  With ``shardings`` (the
+    ``{"params", "opt"}`` dict of :func:`make_train_step`) every leaf is
+    built directly on its devices, never whole on one."""
     from repro.models.params import init_params
-    specs = model_specs(cfg)
-    params = init_params(specs, key)
-    opt = adamw_init(params, plan.knobs.opt_state_dtype)
-    return params, opt
+
+    def init(key):
+        params = init_params(model_specs(cfg), key)
+        return params, adamw_init(params, plan.knobs.opt_state_dtype)
+    if shardings is None:
+        return init(key)
+    return jax.jit(init, out_shardings=(shardings["params"],
+                                        shardings["opt"]))(key)
 
 
-def jit_train_step(cfg: ArchConfig, mesh, plan: Plan, *,
-                   interpret: bool = True, **kw):
+def jit_train_step(cfg: ArchConfig, mesh, plan: Plan, **kw):
     """jit the step with in/out shardings + donation per the plan knobs."""
-    step, sh = make_train_step(cfg, mesh, plan, interpret=interpret, **kw)
+    step, sh = make_train_step(cfg, mesh, plan, **kw)
     if mesh is None:
         return jax.jit(step, donate_argnums=(0, 1)
                        if plan.knobs.donate else ()), sh

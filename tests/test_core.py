@@ -4,10 +4,7 @@ import math
 
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # container lacks hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_arch, get_shape
 from repro.core.combinator import (Combination, GlobalKnobs, clause_grid,

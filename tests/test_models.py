@@ -138,3 +138,24 @@ def test_param_counts_match_nominal():
     for name, (lo, hi) in expect.items():
         n = param_count(model_specs(get_arch(name)))
         assert lo <= n <= hi, f"{name}: {n/1e9:.2f}B outside [{lo},{hi}]"
+
+
+def test_init_params_same_in_every_process():
+    """Weights made from one seed are the same in another process (string
+    hashing is salted per process, so leaf keys must not use hash())."""
+    import os
+    import subprocess
+    import sys
+    code = ("import jax\n"
+            "from repro.configs import get_arch\n"
+            "from repro.models import init_params, model_specs\n"
+            "p = init_params(model_specs(get_arch('stablelm-3b').smoke()),"
+            " jax.random.key(0))\n"
+            "print(repr(sum(float(abs(x.astype('float32')).sum())"
+            " for x in jax.tree.leaves(p))))\n")
+    sums = [subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, env=dict(os.environ, JAX_PLATFORMS="cpu",
+                              PYTHONHASHSEED=str(salt))).stdout
+        for salt in (1, 2)]
+    assert sums[0] and sums[0] == sums[1]

@@ -243,9 +243,16 @@ def test_cache_batch_axes_match_cache_ranks():
         jax.tree.map(check, caches, axes)
 
 
+#: B=1 and B=3 decode are two compiled programs; XLA does not promise
+#: the same rounding across batch sizes, so they agree to f32 tolerance
+ACROSS_PROGRAMS_TOL = 1e-5
+
+
 def test_vector_pos_decode_matches_scalar_rows():
-    """decode_attention with a per-row position vector reproduces the
-    scalar-pos rows exactly (the primitive under the engine contract)."""
+    """decode with a per-row position vector reproduces the scalar-pos
+    rows (the primitive under the engine contract): bit-identical within
+    one compiled program whatever slot a row sits in, and within
+    ``ACROSS_PROGRAMS_TOL`` of the B=1 program."""
     from repro.core.plan import build_contexts
     from repro.models.model import decode_step, init_cache, model_specs
     from repro.models.params import init_params
@@ -281,7 +288,21 @@ def test_vector_pos_decode_matches_scalar_rows():
     batch = init_cache(cfg, B, S)
     for b in range(B):
         batch = _put_row(batch, row_state(b, pos[b]), axes, b)
-    lg, _ = decode_step(params, batch, toks,
-                        jnp.asarray(pos, jnp.int32), cfg, ctxs)
+    step = jax.jit(lambda c, t, p: decode_step(params, c, t, p, cfg,
+                                                ctxs)[0])
+    lg = np.asarray(step(batch, toks, jnp.asarray(pos, jnp.int32)))
     for b in range(B):
-        np.testing.assert_array_equal(np.asarray(lg[b]), per_row[b])
+        np.testing.assert_allclose(lg[b], per_row[b],
+                                   rtol=ACROSS_PROGRAMS_TOL,
+                                   atol=ACROSS_PROGRAMS_TOL)
+
+    # same compiled program, rows moved to other slots: bit-identical
+    perm = [2, 0, 1]
+    moved = init_cache(cfg, B, S)
+    for slot, b in enumerate(perm):
+        moved = _put_row(moved, row_state(b, pos[b]), axes, slot)
+    lg_moved = np.asarray(step(moved, toks[jnp.asarray(perm)],
+                               jnp.asarray([pos[b] for b in perm],
+                                           jnp.int32)))
+    for slot, b in enumerate(perm):
+        np.testing.assert_array_equal(lg_moved[slot], lg[b])

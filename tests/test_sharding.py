@@ -4,10 +4,7 @@ from dataclasses import dataclass
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # container lacks hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_arch

@@ -237,6 +237,22 @@ def test_wallclock_clamps_workers(monkeypatch):
     assert seen["workers"] == 1
 
 
+@pytest.mark.parametrize("backend", ["process", "remote"])
+def test_wallclock_refuses_out_of_process_backends(backend):
+    """A chip belongs to the process holding it: a wallclock sweep must
+    not hand its timings to spawned workers or a scoring server."""
+    cfg = get_arch("granite-8b").smoke()
+    shape = get_shape("train_4k").smoke()
+    t = ComParTuner(cfg, shape, mesh=None, db=SweepDB(":memory:"),
+                    project="wc", mode="new", executor="wallclock")
+    kw = {"remote_url": "http://127.0.0.1:9"} if backend == "remote" else {}
+    with pytest.raises(ValueError, match="scores outside this process"):
+        t.sweep(providers=["fsdp"], max_flags=0, backend=backend, **kw)
+    from repro.core.backends import executor_to_spec
+    with pytest.raises(ValueError, match="process/remote"):
+        executor_to_spec(t.executor)
+
+
 def test_deadline_failures_are_not_cached(tmp_path):
     db = SweepDB(str(tmp_path / "sweep.db"))
     t1, _, _ = _tuner(db, "dl")

@@ -7,10 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:        # container lacks hypothesis: deterministic shim
-    from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.checkpoint.store import CheckpointStore
 from repro.configs import get_arch, get_shape
@@ -184,8 +181,6 @@ def test_hlo_flops_counts_scan_trips():
     assert abs(res["flops"] - expect) / expect < 0.01
     # XLA's own cost_analysis misses the trips — that's why we parse
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):      # jax < 0.5: one dict per device
-        ca = ca[0]
     assert ca["flops"] < res["flops"]
 
 
