@@ -354,7 +354,7 @@ def serve_phase(seed: int, arch: str = SERVE_ARCH,
         _, last, _ = engine._prefill(params, init_cache(cfg, 1, cache_len),
                                      probe)
         logits[name] = np.asarray(last[0], np.float32)
-        out[name] = {"steps": stats.n_steps, "prefills": stats.n_prefills,
+        out[name] = {"steps": stats.n_steps, "admitted": stats.n_admitted,
                      "tokens": stats.n_tokens, "peak_active": stats.peak_active}
         del engine
     rel = float(np.linalg.norm(logits["xla"] - logits["pallas"])

@@ -228,12 +228,13 @@ def attn_decode_shardmap(q, k, v, cache, pos, ctx: ModelContext):
         lo = rank * S_l
         slot = jnp.clip(pos - lo, 0, S_l - 1)
         in_range = (pos >= lo) & (pos < lo + S_l)
-        ck_u = jax.lax.dynamic_update_slice_in_dim(ck, k[:, None], slot,
-                                                   axis=1)
-        cv_u = jax.lax.dynamic_update_slice_in_dim(cv, v[:, None], slot,
-                                                   axis=1)
-        ck = jnp.where(in_range, ck_u, ck)
-        cv = jnp.where(in_range, cv_u, cv)
+        with jax.named_scope("kv_write"):
+            ck_u = jax.lax.dynamic_update_slice_in_dim(ck, k[:, None], slot,
+                                                       axis=1)
+            cv_u = jax.lax.dynamic_update_slice_in_dim(cv, v[:, None], slot,
+                                                       axis=1)
+            ck = jnp.where(in_range, ck_u, ck)
+            cv = jnp.where(in_range, cv_u, cv)
         # local partial attention with global-position mask
         qg = q.reshape(q.shape[0], KV, G, D)
         s = jnp.einsum("bkgd,bskd->bkgs", qg.astype(ck.dtype), ck,
@@ -288,19 +289,24 @@ def attn_decode(p, x1, cache, pos, cfg: ArchConfig, ctx: ModelContext):
         return ctx.constrain(y, ("batch", "embed")), new_cache
     cache_len = cache["k"].shape[1]
     slot = pos % cache_len if cfg.window_size else pos  # ring buffer if windowed
-    if vector_pos:
-        # per-row write: a dynamic_update_slice needs one shared scalar
-        # slot, so select row b's slot with a one-hot mask instead
-        hit = jnp.arange(cache_len)[None, :] == slot[:, None]   # (B,Smax)
-        k_cache = jnp.where(hit[:, :, None, None], k[:, None], cache["k"])
-        v_cache = jnp.where(hit[:, :, None, None], v[:, None], cache["v"])
-    else:
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k[:, None], slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v[:, None], slot, axis=1)
-    k_cache = ctx.constrain(k_cache, ("batch", "kv_seq", "kv_heads", None))
-    v_cache = ctx.constrain(v_cache, ("batch", "kv_seq", "kv_heads", None))
+    with jax.named_scope("kv_write"):
+        if vector_pos:
+            # per-row write: a dynamic_update_slice needs one shared scalar
+            # slot, so select row b's slot with a one-hot mask instead
+            hit = jnp.arange(cache_len)[None, :] == slot[:, None]  # (B,Smax)
+            k_cache = jnp.where(hit[:, :, None, None], k[:, None],
+                                cache["k"])
+            v_cache = jnp.where(hit[:, :, None, None], v[:, None],
+                                cache["v"])
+        else:
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k[:, None], slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v[:, None], slot, axis=1)
+        k_cache = ctx.constrain(k_cache,
+                                ("batch", "kv_seq", "kv_heads", None))
+        v_cache = ctx.constrain(v_cache,
+                                ("batch", "kv_seq", "kv_heads", None))
     if cfg.window_size:
         # ring buffer: all valid entries attendable except future ones
         o = decode_attention(q, k_cache, v_cache,

@@ -1,7 +1,9 @@
 """Block-kind dispatcher: specs / apply / decode / cache-spec per kind.
 
 Kinds: ``attn`` (attention + dense FFN), ``attn_moe`` (attention + MoE FFN),
-``rec`` (RG-LRU + FFN), ``mlstm``, ``slstm``.
+``rec`` (RG-LRU + FFN), ``mlstm``, ``slstm``.  Inside a block the
+attention half runs under ``jax.named_scope("attn")`` and the FFN half
+under ``"mlp"`` (compiled-program metadata only).
 """
 from __future__ import annotations
 
@@ -46,19 +48,22 @@ def block_apply(kind: str, p, x, cfg: ArchConfig, ctx: ModelContext,
     """Full-sequence forward. Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
     if kind in ("attn", "attn_moe"):
-        h = norm_apply(p["ln1"], x, cfg.norm)
-        x = x + A.attn_apply(p["attn"], h, cfg, ctx, positions)
-        h = norm_apply(p["ln2"], x, cfg.norm)
-        if kind == "attn_moe":
-            y, aux = MOE.moe_apply(p["ffn"], h, cfg, ctx)
-        else:
-            y = M.mlp_apply(p["ffn"], h, cfg, ctx)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = norm_apply(p["ln1"], x, cfg.norm)
+            x = x + A.attn_apply(p["attn"], h, cfg, ctx, positions)
+        with jax.named_scope("mlp"):
+            h = norm_apply(p["ln2"], x, cfg.norm)
+            if kind == "attn_moe":
+                y, aux = MOE.moe_apply(p["ffn"], h, cfg, ctx)
+            else:
+                y = M.mlp_apply(p["ffn"], h, cfg, ctx)
+            x = x + y
         return x, aux
     if kind == "rec":
         x = R.rec_apply(p["rec"], x, cfg, ctx)
-        h = norm_apply(p["ln2"], x, cfg.norm)
-        x = x + M.mlp_apply(p["ffn"], h, cfg, ctx)
+        with jax.named_scope("mlp"):
+            h = norm_apply(p["ln2"], x, cfg.norm)
+            x = x + M.mlp_apply(p["ffn"], h, cfg, ctx)
         return x, aux
     if kind == "mlstm":
         return X.mlstm_apply(p, x, cfg, ctx), aux
@@ -84,20 +89,23 @@ def block_decode(kind: str, p, x1, cache, pos, cfg: ArchConfig,
                  ctx: ModelContext):
     """One-token decode. x1: (B,d). Returns (x1, new_cache)."""
     if kind in ("attn", "attn_moe"):
-        h = norm_apply(p["ln1"], x1[:, None], cfg.norm)[:, 0]
-        y, new_cache = A.attn_decode(p["attn"], h, cache, pos, cfg, ctx)
-        x1 = x1 + y
-        h = norm_apply(p["ln2"], x1[:, None], cfg.norm)
-        if kind == "attn_moe":
-            y, _ = MOE.moe_apply(p["ffn"], h, cfg, ctx)
-        else:
-            y = M.mlp_apply(p["ffn"], h, cfg, ctx)
-        return x1 + y[:, 0], new_cache
+        with jax.named_scope("attn"):
+            h = norm_apply(p["ln1"], x1[:, None], cfg.norm)[:, 0]
+            y, new_cache = A.attn_decode(p["attn"], h, cache, pos, cfg, ctx)
+            x1 = x1 + y
+        with jax.named_scope("mlp"):
+            h = norm_apply(p["ln2"], x1[:, None], cfg.norm)
+            if kind == "attn_moe":
+                y, _ = MOE.moe_apply(p["ffn"], h, cfg, ctx)
+            else:
+                y = M.mlp_apply(p["ffn"], h, cfg, ctx)
+            return x1 + y[:, 0], new_cache
     if kind == "rec":
         x1, new_cache = R.rec_decode(p["rec"], x1, cache, cfg, ctx)
-        h = norm_apply(p["ln2"], x1[:, None], cfg.norm)
-        y = M.mlp_apply(p["ffn"], h, cfg, ctx)
-        return x1 + y[:, 0], new_cache
+        with jax.named_scope("mlp"):
+            h = norm_apply(p["ln2"], x1[:, None], cfg.norm)
+            y = M.mlp_apply(p["ffn"], h, cfg, ctx)
+            return x1 + y[:, 0], new_cache
     if kind == "mlstm":
         return X.mlstm_decode(p, x1, cache, cfg, ctx)
     if kind == "slstm":

@@ -4,7 +4,9 @@ The layer stack is split into homogeneous *scan groups*
 (``ArchConfig.stack_plan``); each group is one ComParX **segment** with its
 own :class:`ModelContext` (sharding rules + execution clause).  Groups with
 ``repeats > 1`` are executed with ``jax.lax.scan`` over stacked parameters
-so the HLO stays compact at any depth.
+so the HLO stays compact at any depth.  Each segment runs under a
+``jax.named_scope`` of its name (``embed``, ``g<i>``, ``head``), which
+lands in the compiled program's ``op_name`` metadata and nowhere else.
 """
 from __future__ import annotations
 
@@ -111,19 +113,22 @@ def forward(params, batch, cfg: ArchConfig, ctxs):
     Returns (logits (B,S,V) f32, aux_loss scalar).
     """
     ectx = _ctx_for(ctxs, SEG_EMBED)
-    if "embeds" in batch:          # vlm/audio stub frontend
-        x = ectx.constrain(batch["embeds"], ("batch", "seq", "embed"))
-    else:
-        x = embed_tokens(params, batch["tokens"], cfg, ectx)
+    with jax.named_scope(SEG_EMBED):
+        if "embeds" in batch:          # vlm/audio stub frontend
+            x = ectx.constrain(batch["embeds"], ("batch", "seq", "embed"))
+        else:
+            x = embed_tokens(params, batch["tokens"], cfg, ectx)
     S = x.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     aux = jnp.zeros((), jnp.float32)
     for gi, group in enumerate(cfg.stack_plan()):
         seg = f"g{gi}"
-        x, a = _run_group(x, params[seg], group, cfg, _ctx_for(ctxs, seg),
-                          positions)
+        with jax.named_scope(seg):
+            x, a = _run_group(x, params[seg], group, cfg,
+                              _ctx_for(ctxs, seg), positions)
         aux = aux + a
-    logits = lm_head(params, x, cfg, _ctx_for(ctxs, SEG_HEAD))
+    with jax.named_scope(SEG_HEAD):
+        logits = lm_head(params, x, cfg, _ctx_for(ctxs, SEG_HEAD))
     return logits, aux
 
 
@@ -155,8 +160,8 @@ def decode_step(params, caches, tokens, pos, cfg: ArchConfig, ctxs):
 
     Returns (logits (B,V) f32, new caches).
     """
-    ectx = _ctx_for(ctxs, SEG_EMBED)
-    x = embed_tokens(params, tokens, cfg, ectx)
+    with jax.named_scope(SEG_EMBED):
+        x = embed_tokens(params, tokens, cfg, _ctx_for(ctxs, SEG_EMBED))
     new_caches = {}
     for gi, group in enumerate(cfg.stack_plan()):
         seg = f"g{gi}"
@@ -171,15 +176,17 @@ def decode_step(params, caches, tokens, pos, cfg: ArchConfig, ctxs):
                 new_cache[f"b{j}"] = c
             return x, new_cache
 
-        if group.repeats == 1:
-            x, new_caches[seg] = superblock(x, gparams, gcache)
-        else:
-            def step(x, pc):
-                lp, lc = pc
-                x, nc = superblock(x, lp, lc)
-                return x, nc
-            x, new_caches[seg] = jax.lax.scan(
-                step, x, (gparams, gcache),
-                unroll=ctx.clause.scan_unroll)
-    logits = lm_head(params, x, cfg, _ctx_for(ctxs, SEG_HEAD))
+        with jax.named_scope(seg):
+            if group.repeats == 1:
+                x, new_caches[seg] = superblock(x, gparams, gcache)
+            else:
+                def step(x, pc):
+                    lp, lc = pc
+                    x, nc = superblock(x, lp, lc)
+                    return x, nc
+                x, new_caches[seg] = jax.lax.scan(
+                    step, x, (gparams, gcache),
+                    unroll=ctx.clause.scan_unroll)
+    with jax.named_scope(SEG_HEAD):
+        logits = lm_head(params, x, cfg, _ctx_for(ctxs, SEG_HEAD))
     return logits, new_caches

@@ -23,18 +23,31 @@ one-request-at-a-time baseline, on the very same compiled program.
 Tested in tests/test_serve.py.  The contract holds for dense archs; MoE
 routing mixes rows across the batch (capacity/dispatch are global), so
 the engine warns on MoE configs.
+
+**Spans.**  ``run`` writes host spans into the profiler's trace (a no-op
+check when no profiler session is on): ``serve.run`` per call;
+``serve.admit`` per admission (arguments ``rid``, ``slot``,
+``prompt_len``) with children ``serve.prefill`` (the prefill call and
+the first-token readback) and ``serve.splice``; ``serve.step`` per
+batched decode iteration (arguments ``step``, ``active``) with children
+``serve.dispatch`` (uploads and the call) and ``serve.readback``, the
+slot loop being its self time.  Admit and step spans never overlap and
+cover the run but for the loop control.  Each ``Completion`` carries
+its request's ``time.perf_counter()`` stamps: arrived (the call, in an
+offline run), admitted, first token held by the host, done.
 """
 from __future__ import annotations
 
 import logging
 import time
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ArchConfig
 from repro.core.plan import Plan
@@ -74,6 +87,11 @@ class Completion:
     slot: int
     admitted_step: int              # engine step at admission
     done_step: int
+    # time.perf_counter() stamps; streams compare equal whatever they read
+    arrived_s: float = field(compare=False)
+    admitted_s: float = field(compare=False)
+    first_token_s: float = field(compare=False)
+    done_s: float = field(compare=False)
 
 
 @dataclass
@@ -83,7 +101,6 @@ class ServeStats:
     n_admitted: int = 0
     n_completed: int = 0
     n_steps: int = 0                # batched decode steps
-    n_prefills: int = 0
     n_prefill_tokens: int = 0
     n_tokens: int = 0               # generated tokens
     occupancy_sum: float = 0.0      # sum over steps of active/capacity
@@ -102,7 +119,6 @@ class ServeStats:
     def summary(self) -> str:
         return (f"capacity={self.capacity} admitted={self.n_admitted} "
                 f"completed={self.n_completed} steps={self.n_steps} "
-                f"prefills={self.n_prefills} "
                 f"prefill_tokens={self.n_prefill_tokens} "
                 f"tokens={self.n_tokens} occupancy={self.occupancy:.2f} "
                 f"peak_active={self.peak_active} "
@@ -114,6 +130,9 @@ class _Slot:
     req: Request
     generated: List[int]
     admitted_step: int
+    arrived_s: float
+    admitted_s: float
+    first_token_s: float
 
 
 def cache_batch_axes(cfg: ArchConfig):
@@ -204,49 +223,58 @@ class ServeEngine:
         stats = self.stats = ServeStats(capacity=B)
         done: Dict[str, Completion] = {}
         step_i = 0
-        t0 = time.perf_counter()
-        while queue or any(s is not None for s in slots):
-            # admission: fill free slots up to the active cap
-            active = sum(s is not None for s in slots)
-            for s in range(B):
-                if not queue or active >= cap:
-                    break
-                if slots[s] is not None:
+        with TraceAnnotation("serve.run"):
+            t0 = time.perf_counter()    # offline: every request arrives now
+            while queue or any(s is not None for s in slots):
+                # admission: fill free slots up to the active cap
+                active = sum(s is not None for s in slots)
+                for s in range(B):
+                    if not queue or active >= cap:
+                        break
+                    if slots[s] is not None:
+                        continue
+                    req = queue.popleft()
+                    with TraceAnnotation("serve.admit", rid=req.rid, slot=s,
+                                         prompt_len=len(req.prompt)):
+                        admitted = time.perf_counter()
+                        caches, first = self._admit(caches, s, req, stats)
+                        slots[s] = _Slot(req, [first], step_i, t0, admitted,
+                                         time.perf_counter())
+                        tokens[s] = first
+                        pos[s] = len(req.prompt)
+                        active += 1
+                        # a 1-token request (or instant EOS) never enters
+                        # the batched step; its slot frees immediately
+                        if self._finish_if_done(slots, s, tokens, pos, done,
+                                                stats, step_i):
+                            active -= 1
+                if not any(s is not None for s in slots):
                     continue
-                req = queue.popleft()
-                caches, first = self._admit(caches, s, req, stats)
-                slots[s] = _Slot(req, [first], step_i)
-                tokens[s] = first
-                pos[s] = len(req.prompt)
-                active += 1
-                # a 1-token request (or instant EOS) never enters the
-                # batched step; its slot frees immediately
-                if self._finish_if_done(slots, s, tokens, pos, done,
-                                        stats, step_i):
-                    active -= 1
-            if not any(s is not None for s in slots):
-                continue
-            # one batched decode step, per-slot positions
-            nxt, _, caches = self._step(self.params, caches,
-                                        jnp.asarray(tokens),
-                                        jnp.asarray(pos))
-            step_i += 1
-            n_act = sum(s is not None for s in slots)
-            stats.n_steps += 1
-            stats.occupancy_sum += n_act / B
-            stats.peak_active = max(stats.peak_active, n_act)
-            nxt_np = np.asarray(nxt)
-            for s in range(B):
-                sl = slots[s]
-                if sl is None:
-                    continue
-                tok = int(nxt_np[s])
-                sl.generated.append(tok)
-                stats.n_tokens += 1
-                tokens[s] = tok
-                pos[s] += 1
-                self._finish_if_done(slots, s, tokens, pos, done, stats,
-                                     step_i)
+                n_act = sum(s is not None for s in slots)
+                with TraceAnnotation("serve.step", step=step_i,
+                                     active=n_act):
+                    # one batched decode step, per-slot positions
+                    with TraceAnnotation("serve.dispatch"):
+                        nxt, _, caches = self._step(self.params, caches,
+                                                    jnp.asarray(tokens),
+                                                    jnp.asarray(pos))
+                    with TraceAnnotation("serve.readback"):
+                        nxt_np = np.asarray(nxt)
+                    step_i += 1
+                    stats.n_steps += 1
+                    stats.occupancy_sum += n_act / B
+                    stats.peak_active = max(stats.peak_active, n_act)
+                    for s in range(B):
+                        sl = slots[s]
+                        if sl is None:
+                            continue
+                        tok = int(nxt_np[s])
+                        sl.generated.append(tok)
+                        stats.n_tokens += 1
+                        tokens[s] = tok
+                        pos[s] += 1
+                        self._finish_if_done(slots, s, tokens, pos, done,
+                                             stats, step_i)
         stats.elapsed_s = time.perf_counter() - t0
         return done
 
@@ -258,13 +286,15 @@ class ServeEngine:
         prompt = jnp.asarray(
             np.asarray(req.prompt, np.int32)[None, :])
         fresh = init_cache(self.cfg, 1, self.cache_len)
-        first, _, filled = self._prefill(self.params, fresh, prompt)
-        caches = _put_row(caches, filled, self._axes, s)
+        with TraceAnnotation("serve.prefill"):
+            first, _, filled = self._prefill(self.params, fresh, prompt)
+            first = int(np.asarray(first)[0])
+        with TraceAnnotation("serve.splice"):
+            caches = _put_row(caches, filled, self._axes, s)
         stats.n_admitted += 1
-        stats.n_prefills += 1
         stats.n_prefill_tokens += len(req.prompt)
         stats.n_tokens += 1                   # the prefill's first token
-        return caches, int(np.asarray(first)[0])
+        return caches, first
 
     @staticmethod
     def _finish_if_done(slots, s: int, tokens, pos, done, stats,
@@ -279,7 +309,8 @@ class ServeEngine:
             return False
         done[req.rid] = Completion(
             req.rid, len(req.prompt), list(sl.generated), reason, s,
-            sl.admitted_step, step_i)
+            sl.admitted_step, step_i, sl.arrived_s, sl.admitted_s,
+            sl.first_token_s, time.perf_counter())
         slots[s] = None
         tokens[s] = 0
         pos[s] = 0

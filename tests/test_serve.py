@@ -306,3 +306,94 @@ def test_vector_pos_decode_matches_scalar_rows():
                                            jnp.int32)))
     for slot, b in enumerate(perm):
         np.testing.assert_array_equal(lg_moved[slot], lg[b])
+
+
+# --- spans, stamps and scopes -------------------------------------------------
+
+def _host_spans(run):
+    """Run ``run()`` under the profiler; the host events whose names
+    start with ``serve.`` as (name, start_ns, end_ns, stats), by start."""
+    import tempfile
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d)
+        try:
+            out = run()
+        finally:
+            jax.profiler.stop_trace()
+        pd = ProfileData.from_file(str(next(Path(d).rglob("*.xplane.pb"))))
+        spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                  dict(e.stats))
+                 for p in pd.planes if p.name.startswith("/host:CPU")
+                 for ln in p.lines for e in ln.events
+                 if e.name.startswith("serve.")]
+    return out, sorted(spans, key=lambda s: (s[1], -s[2]))
+
+
+def test_engine_spans_nest_and_cover_each_admission_and_step():
+    from bench import serve_trace as st
+    cfg = _cfg()
+    eng = ServeEngine(cfg, _plan(cfg), capacity=3, cache_len=32)
+    reqs = _reqs(cfg, 5, tokens=4)
+    done, spans = _host_spans(lambda: eng.run(reqs))
+    assert {s[0] for s in spans} == set(st.SPANS)
+    by = lambda n: [s for s in spans if s[0] == n]  # noqa: E731
+    (run,) = by(st.RUN_SPAN)
+    admits, steps = by(st.ADMIT_SPAN), by(st.STEP_SPAN)
+    assert len(admits) == len(reqs) == len(done)
+    assert len(steps) == eng.stats.n_steps
+    assert sorted(a[3]["rid"] for a in admits) == sorted(r.rid for r in reqs)
+    for a in admits:
+        c = done[a[3]["rid"]]
+        assert (a[3]["slot"], a[3]["prompt_len"]) == (c.slot, c.prompt_len)
+    assert [s[3]["step"] for s in steps] == list(range(len(steps)))
+    assert all(1 <= s[3]["active"] <= 3 for s in steps)
+
+    def inside(child, parent):
+        return parent[1] <= child[1] and child[2] <= parent[2]
+
+    for parents, kids in ((admits, (st.PREFILL_SPAN, st.SPLICE_SPAN)),
+                          (steps, (st.DISPATCH_SPAN, st.READBACK_SPAN))):
+        for k in kids:
+            children = by(k)
+            assert len(children) == len(parents)
+            assert all(inside(c, p) for c, p in zip(children, parents))
+    # admit and step spans lie in the run and never overlap one another
+    top = sorted(admits + steps, key=lambda s: s[1])
+    assert all(inside(s, run) for s in top)
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+
+
+def test_completion_stamps_are_ordered_and_sequential_admits_wait():
+    cfg = _cfg()
+    eng = ServeEngine(cfg, _plan(cfg), capacity=3, cache_len=32)
+    reqs = _reqs(cfg, 4, tokens=3)
+    for done in (eng.run(reqs), eng.run(reqs, max_active=1)):
+        for c in done.values():
+            assert c.arrived_s <= c.admitted_s <= c.first_token_s \
+                <= c.done_s
+        assert len({c.arrived_s for c in done.values()}) == 1
+    # one at a time: each admission waits for the previous request
+    order = [done[r.rid] for r in reqs]
+    for prev, nxt in zip(order, order[1:]):
+        assert nxt.admitted_s >= prev.done_s
+
+
+def test_decode_step_carries_segment_block_and_kv_write_scopes():
+    from bench.serve_trace import in_scope, scope_paths
+    from repro.models.model import cache_specs
+    cfg = _cfg()
+    eng = ServeEngine(cfg, _plan(cfg), capacity=2, cache_len=16)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype)  # noqa: E731
+    i32 = jax.ShapeDtypeStruct((2,), jnp.int32)
+    text = eng._step.lower(jax.tree.map(sds, eng.params),
+                           cache_specs(cfg, 2, 16), i32, i32) \
+        .compile().as_text()
+    paths = scope_paths(text).values()
+    for scope in ("embed", "attn", "kv_write", "mlp", "head"):
+        assert any(in_scope(p, scope) for p in paths), scope
+    # the cache write sits inside attention, in the layer group's scan
+    assert all(in_scope(p, "attn") and in_scope(p, "g0")
+               for p in paths if in_scope(p, "kv_write"))
