@@ -24,8 +24,9 @@ from repro.core.providers import get_provider
 from repro.core.segment import Segment
 from repro.models.context import ModelContext
 from repro.models.loss import softmax_xent
-from repro.models.model import (SEG_EMBED, cache_specs, embed_tokens,
-                                lm_head, model_specs, _run_group)
+from repro.models.model import (SEG_EMBED, cache_specs, decode_group,
+                                embed_tokens, lm_head, model_specs,
+                                _run_group)
 from repro.models.params import abstract_params, param_pspecs
 from repro.runtime.sharding import Rules
 
@@ -120,19 +121,8 @@ def segment_program(cfg: ArchConfig, shape: ShapeConfig, seg: Segment,
         pos = jax.ShapeDtypeStruct((), i32)
 
         def fn(p, caches, x, pos):
-            from repro.models.blocks import block_decode
-
-            def superblock(x, lp, lc):
-                nc = {}
-                for j, kind in enumerate(group.pattern):
-                    x, c = block_decode(kind, lp[f"b{j}"], x, lc[f"b{j}"],
-                                        pos, cfg, ctx)
-                    nc[f"b{j}"] = c
-                return x, nc
-            if group.repeats == 1:
-                return superblock(x, p, caches)
-            return jax.lax.scan(
-                lambda x, pc: superblock(x, *pc), x, (p, caches))
+            return decode_group(x, p, caches, group, cfg,
+                                ctx.with_(decode=True), pos)
         return fn, (p_abs, cspecs, x_sds, pos), (p_sh, c_sh, x_sh, None)
 
     def fn(p, x):

@@ -40,7 +40,7 @@ log = logging.getLogger("repro.autotune")
 
 #: bump on any change to what the microbenchmarks measure or how rows
 #: are keyed — stale-version rows are then unreachable (never trusted).
-KERNEL_CACHE_VERSION = 1
+KERNEL_CACHE_VERSION = 2
 
 #: the SegmentClause fields that select each op's schedule, in the order
 #: they are keyed.  ``scan_unroll`` is deliberately absent: it shapes the
@@ -176,14 +176,16 @@ def _op_program(op: str, fields: Dict[str, object], cfg, shape):
 
     if op == "flash_decode":
         q = jax.ShapeDtypeStruct((B, H, D), dt)
-        cache = jax.ShapeDtypeStruct((B, S, KV, D), dt)
+        cache = jax.ShapeDtypeStruct((B, S, KV * D), dt)   # stored rows
         bk = int(fields["block_k"])
         pos = S // 2                       # mid-cache: the typical token
         if kernel == "pallas":
             from repro.kernels.ops import flash_decode
 
             def fn(q, k, v):
-                return flash_decode(q, k, v, pos, block_k=bk)
+                heads = (B, S, KV, D)
+                return flash_decode(q, k.reshape(heads), v.reshape(heads),
+                                    pos, block_k=bk)
         else:
             from repro.models.attention import decode_attention
 

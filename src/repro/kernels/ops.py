@@ -59,7 +59,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
 def flash_decode(q, k_cache, v_cache, pos, *, block_k: int = 1024,
                  interpret: Optional[bool] = None, return_lse: bool = False):
-    """Model layout: q (B,H,D); caches (B,Smax,KV,D)."""
+    """q (B,H,D); caches (B,Smax,KV,D): the decode cache's stored
+    (B,Smax,KV*D) rows split into heads."""
     kh = jnp.moveaxis(k_cache, 2, 1)                   # (B,KV,Smax,D)
     vh = jnp.moveaxis(v_cache, 2, 1)
     return flash_decode_fwd(q, kh, vh, pos, block_k=block_k,

@@ -20,12 +20,14 @@ from repro.models.context import ModelContext
 from repro.models.layers import norm_apply, norm_specs
 
 BLOCK_KINDS = ("attn", "attn_moe", "rec", "mlstm", "slstm")
+#: kinds whose decode cache is a KV cache, written in place (attn_decode)
+ATTN_KINDS = ("attn", "attn_moe")
 
 
 def block_specs(kind: str, cfg: ArchConfig):
     dt = cfg.dtype
     d = cfg.d_model
-    if kind in ("attn", "attn_moe"):
+    if kind in ATTN_KINDS:
         s = {"ln1": norm_specs(d, cfg.norm, dt),
              "attn": A.attn_specs(cfg),
              "ln2": norm_specs(d, cfg.norm, dt)}
@@ -47,7 +49,7 @@ def block_apply(kind: str, p, x, cfg: ArchConfig, ctx: ModelContext,
                 positions):
     """Full-sequence forward. Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    if kind in ("attn", "attn_moe"):
+    if kind in ATTN_KINDS:
         with jax.named_scope("attn"):
             h = norm_apply(p["ln1"], x, cfg.norm)
             x = x + A.attn_apply(p["attn"], h, cfg, ctx, positions)
@@ -74,7 +76,7 @@ def block_apply(kind: str, p, x, cfg: ArchConfig, ctx: ModelContext,
 
 def block_cache_spec(kind: str, cfg: ArchConfig, batch: int, smax: int):
     """Abstract per-layer decode cache/state."""
-    if kind in ("attn", "attn_moe"):
+    if kind in ATTN_KINDS:
         return A.attn_cache_spec(cfg, batch, smax)
     if kind == "rec":
         return R.rec_state_spec(cfg, batch)
@@ -86,12 +88,19 @@ def block_cache_spec(kind: str, cfg: ArchConfig, batch: int, smax: int):
 
 
 def block_decode(kind: str, p, x1, cache, pos, cfg: ArchConfig,
-                 ctx: ModelContext):
-    """One-token decode. x1: (B,d). Returns (x1, new_cache)."""
-    if kind in ("attn", "attn_moe"):
+                 ctx: ModelContext, layer=None):
+    """One-token decode. x1: (B,d). Returns (x1, new_cache).
+
+    The attention kinds get their group's whole KV stacks and ``layer``,
+    the index of this layer in them
+    (:func:`repro.models.attention.attn_decode`); recurrent kinds get
+    their own layer's state.
+    """
+    if kind in ATTN_KINDS:
         with jax.named_scope("attn"):
             h = norm_apply(p["ln1"], x1[:, None], cfg.norm)[:, 0]
-            y, new_cache = A.attn_decode(p["attn"], h, cache, pos, cfg, ctx)
+            y, new_cache = A.attn_decode(p["attn"], h, cache, pos, cfg, ctx,
+                                         layer)
             x1 = x1 + y
         with jax.named_scope("mlp"):
             h = norm_apply(p["ln2"], x1[:, None], cfg.norm)

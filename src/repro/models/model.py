@@ -16,8 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, ScanGroup
-from repro.models.blocks import (block_apply, block_cache_spec, block_decode,
-                                 block_specs)
+from repro.models.blocks import (ATTN_KINDS, block_apply, block_cache_spec,
+                                 block_decode, block_specs)
 from repro.models.context import ModelContext
 from repro.models.layers import norm_apply, norm_specs
 from repro.models.params import ParamSpec, stack_specs
@@ -155,10 +155,60 @@ def init_cache(cfg: ArchConfig, batch: int, smax: int):
                         cache_specs(cfg, batch, smax))
 
 
-def decode_step(params, caches, tokens, pos, cfg: ArchConfig, ctxs):
-    """One decoding step. tokens: (B,) int32; pos: scalar int32.
+def decode_group(x, gparams, gcache, group: ScanGroup, cfg: ArchConfig,
+                 ctx: ModelContext, pos):
+    """One scan group of the decode step. Returns (x, new gcache).
 
-    Returns (logits (B,V) f32, new caches).
+    Each attention block's KV stack (L,B,Smax,KV*D) is loop state of
+    the group's layer loop: layer ``l`` writes its B new rows into the
+    carried stack and reads its own slice of it, read-only
+    (:func:`repro.models.attention.attn_decode`), so no layer slice is
+    cut out and re-stacked and, with the stack donated, the step updates
+    the cache in place.  A group that is not scanned passes its caches
+    as one-layer stacks.  Recurrent states (a few KB a slot) pass
+    through the scan's inputs and outputs.
+    """
+    kv = {f"b{j}" for j, kind in enumerate(group.pattern)
+          if kind in ATTN_KINDS}
+
+    def superblock(x, lp, lc, layer):
+        nc = {}
+        for j, kind in enumerate(group.pattern):
+            b = f"b{j}"
+            x, nc[b] = block_decode(kind, lp[b], x, lc[b], pos, cfg, ctx,
+                                    layer)
+        return x, nc
+
+    if group.repeats == 1:
+        one = lambda t: jax.tree.map(lambda c: c[None], t)  # noqa: E731
+        x, nc = superblock(x, gparams, {b: one(c) if b in kv else c
+                                        for b, c in gcache.items()}, 0)
+        return x, {b: jax.tree.map(lambda c: c[0], c) if b in kv else c
+                   for b, c in nc.items()}
+
+    def step(carry, xs):
+        x, stacks = carry
+        lp, states, layer = xs
+        x, nc = superblock(x, lp, {**stacks, **states}, layer)
+        return ((x, {b: c for b, c in nc.items() if b in kv}),
+                {b: c for b, c in nc.items() if b not in kv})
+
+    stacks = {b: c for b, c in gcache.items() if b in kv}
+    states = {b: c for b, c in gcache.items() if b not in kv}
+    (x, stacks), states = jax.lax.scan(
+        step, (x, stacks),
+        (gparams, states, jnp.arange(group.repeats, dtype=jnp.int32)),
+        unroll=ctx.clause.scan_unroll)
+    return x, {**stacks, **states}
+
+
+def decode_step(params, caches, tokens, pos, cfg: ArchConfig, ctxs):
+    """One decoding step. tokens: (B,) int32; pos: scalar int32, or (B,)
+    per-row positions.
+
+    Returns (logits (B,V) f32, new caches).  The caches are updated in
+    place when the caller donates them (:func:`decode_group`): each
+    attention layer writes only its B new rows.
     """
     with jax.named_scope(SEG_EMBED):
         x = embed_tokens(params, tokens, cfg, _ctx_for(ctxs, SEG_EMBED))
@@ -166,27 +216,9 @@ def decode_step(params, caches, tokens, pos, cfg: ArchConfig, ctxs):
     for gi, group in enumerate(cfg.stack_plan()):
         seg = f"g{gi}"
         ctx = _ctx_for(ctxs, seg).with_(decode=True)
-        gparams, gcache = params[seg], caches[seg]
-
-        def superblock(x, layer_params, layer_cache):
-            new_cache = {}
-            for j, kind in enumerate(group.pattern):
-                x, c = block_decode(kind, layer_params[f"b{j}"], x,
-                                    layer_cache[f"b{j}"], pos, cfg, ctx)
-                new_cache[f"b{j}"] = c
-            return x, new_cache
-
         with jax.named_scope(seg):
-            if group.repeats == 1:
-                x, new_caches[seg] = superblock(x, gparams, gcache)
-            else:
-                def step(x, pc):
-                    lp, lc = pc
-                    x, nc = superblock(x, lp, lc)
-                    return x, nc
-                x, new_caches[seg] = jax.lax.scan(
-                    step, x, (gparams, gcache),
-                    unroll=ctx.clause.scan_unroll)
+            x, new_caches[seg] = decode_group(x, params[seg], caches[seg],
+                                              group, cfg, ctx, pos)
     with jax.named_scope(SEG_HEAD):
         logits = lm_head(params, x, cfg, _ctx_for(ctxs, SEG_HEAD))
     return logits, new_caches

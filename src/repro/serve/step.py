@@ -15,6 +15,8 @@ from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core.plan import Plan, build_contexts
+from repro.models.attention import CACHE_AXES
+from repro.models.blocks import ATTN_KINDS
 from repro.models.model import (SEG_EMBED, SEG_HEAD, cache_specs,
                                 decode_step, forward)
 from repro.models.rglru import rglru_dims  # noqa: F401  (docs reference)
@@ -23,9 +25,8 @@ from repro.models.rglru import rglru_dims  # noqa: F401  (docs reference)
 def cache_axes(cfg: ArchConfig):
     """Logical axes mirroring ``models.model.cache_specs`` structure."""
     def for_kind(kind: str):
-        if kind in ("attn", "attn_moe"):
-            a = ("batch", "kv_seq", "kv_heads", None)
-            return {"k": a, "v": a}
+        if kind in ATTN_KINDS:
+            return {"k": CACHE_AXES, "v": CACHE_AXES}
         if kind == "rec":
             return {"h": ("batch", "rnn"), "conv": ("batch", None, "rnn")}
         if kind == "mlstm":

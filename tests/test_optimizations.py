@@ -52,16 +52,17 @@ def test_shardmap_decode_matches_pjit(mesh11):
     rules = Rules({"batch": "data", "kv_seq": "model", "kv_heads": None},
                   mesh11)
     B, S = 2, 32
-    zero = {"k": jnp.zeros((B, S, cfg.num_kv_heads, cfg.head_dim_)),
-            "v": jnp.zeros((B, S, cfg.num_kv_heads, cfg.head_dim_))}
+    # a one-layer stack of the cache's stored rows
+    stack = (1, B, S, cfg.num_kv_heads * cfg.head_dim_)
+    zero = {"k": jnp.zeros(stack), "v": jnp.zeros(stack)}
     x = jax.random.normal(jax.random.key(1), (B, cfg.d_model)) * 0.3
     ctx0 = ModelContext(rules=rules, clause=SegmentClause())
     ctx1 = ModelContext(rules=rules,
                         clause=SegmentClause(decode_shardmap=True))
     c0, c1 = dict(zero), dict(zero)
     for pos in range(6):
-        y0, c0 = A.attn_decode(p, x, c0, jnp.int32(pos), cfg, ctx0)
-        y1, c1 = A.attn_decode(p, x, c1, jnp.int32(pos), cfg, ctx1)
+        y0, c0 = A.attn_decode(p, x, c0, jnp.int32(pos), cfg, ctx0, 0)
+        y1, c1 = A.attn_decode(p, x, c1, jnp.int32(pos), cfg, ctx1, 0)
         np.testing.assert_allclose(np.asarray(y0), np.asarray(y1),
                                    atol=2e-4, rtol=1e-3)
         np.testing.assert_allclose(np.asarray(c0["k"]),
@@ -78,6 +79,25 @@ def test_bf16_cache_read_matches_upcast(pos):
     np.testing.assert_allclose(np.asarray(o1, np.float32),
                                np.asarray(o2, np.float32),
                                atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("upcast", [True, False])
+@pytest.mark.parametrize("pos", [0, 40, 63, "rows"])
+def test_decode_attention_whole_rows_match_per_head(pos, upcast):
+    """Contracting the stored (B,S,KV*D) rows block-diagonally gives the
+    per-KV-head contraction's output (GQA, G=2), for a scalar position
+    and per-row ones."""
+    B, S, KV, D = 3, 64, 2, 16
+    q = jax.random.normal(jax.random.key(2), (B, 2 * KV, D), jnp.bfloat16)
+    kc = jax.random.normal(jax.random.key(3), (B, S, KV, D), jnp.bfloat16)
+    vc = jax.random.normal(jax.random.key(4), (B, S, KV, D), jnp.bfloat16)
+    p = jnp.array([0, 17, 63]) if pos == "rows" else pos
+    heads = A.decode_attention(q, kc, vc, p, upcast=upcast)
+    rows = A.decode_attention(q, kc.reshape(B, S, KV * D),
+                              vc.reshape(B, S, KV * D), p, upcast=upcast)
+    np.testing.assert_allclose(np.asarray(rows, np.float32),
+                               np.asarray(heads, np.float32),
+                               atol=1e-2, rtol=1e-2)
 
 
 def test_windowed_chunked_attention_no_full_copies():

@@ -308,6 +308,133 @@ def test_vector_pos_decode_matches_scalar_rows():
         np.testing.assert_array_equal(lg_moved[slot], lg[b])
 
 
+def _onehot_attn_decode(p, x1, cache, pos, cfg):
+    """The cache write and read as the decode step once did them: a
+    one-hot ``jnp.where`` over the layer's whole (B,S,KV*D) slice, then
+    each head cut out of the rows for the contraction."""
+    from repro.models.layers import apply_rope, dense
+    q = apply_rope(dense(x1, p["wq"]), pos, cfg.rope)
+    k = apply_rope(dense(x1, p["wk"]), pos, cfg.rope)
+    v = dense(x1, p["wv"])
+    B, S, _ = cache["k"].shape
+    (H, D), KV = q.shape[1:], k.shape[1]
+    slot = pos % S if cfg.window_size else pos
+    if jnp.ndim(pos):
+        hit = jnp.arange(S)[None, :] == slot[:, None]
+        seen = jnp.arange(S)[None, :] <= jnp.minimum(pos, S - 1)[:, None]
+    else:
+        hit = (jnp.arange(S) == slot)[None]
+        seen = (jnp.arange(S) <= jnp.minimum(pos, S - 1))[None]
+    kc = jnp.where(hit[:, :, None], k.reshape(B, 1, -1), cache["k"])
+    vc = jnp.where(hit[:, :, None], v.reshape(B, 1, -1), cache["v"])
+    f32 = jnp.float32
+    qg = q.reshape(B, KV, H // KV, D).astype(f32)
+    s = jnp.einsum("bkgd,bskd->bkgs", qg,
+                   kc.reshape(B, S, KV, D).astype(f32)) * D ** -0.5
+    s = jnp.where(seen[:, None, None], s, -1e30)
+    o = jnp.einsum("bkgs,bskd->bkgd", jax.nn.softmax(s, axis=-1),
+                   vc.reshape(B, S, KV, D).astype(f32))
+    y = jnp.einsum("bhd,hde->be", o.reshape(B, H, D).astype(q.dtype),
+                   p["wo"]).astype(x1.dtype)
+    return y, {"k": kc, "v": vc}
+
+
+def _onehot_decode_step(params, caches, tokens, pos, cfg):
+    """Reference decode step: every layer's cache slice scanned in as
+    ``xs`` and re-emitted as ``ys``, written by ``_onehot_attn_decode``;
+    recurrent blocks run ``block_decode`` on their own state."""
+    from repro.models.blocks import ATTN_KINDS, block_decode
+    from repro.models.context import ModelContext
+    from repro.models.layers import norm_apply
+    from repro.models.mlp import mlp_apply
+    from repro.models.model import embed_tokens, lm_head
+    ctx = ModelContext()
+    x = embed_tokens(params, tokens, cfg, ctx)
+    new = {}
+    for gi, group in enumerate(cfg.stack_plan()):
+        def layer(x, pc):
+            lp, lc = pc
+            nc = {}
+            for j, kind in enumerate(group.pattern):
+                b, p = f"b{j}", lp[f"b{j}"]
+                if kind in ATTN_KINDS:
+                    h = norm_apply(p["ln1"], x[:, None], cfg.norm)[:, 0]
+                    y, nc[b] = _onehot_attn_decode(p["attn"], h, lc[b], pos,
+                                                   cfg)
+                    x = x + y
+                    h = norm_apply(p["ln2"], x[:, None], cfg.norm)
+                    x = x + mlp_apply(p["ffn"], h, cfg, ctx)[:, 0]
+                else:
+                    x, nc[b] = block_decode(kind, p, x, lc[b], pos, cfg, ctx)
+            return x, nc
+        seg = f"g{gi}"
+        pc = (params[seg], caches[seg])
+        x, new[seg] = layer(x, pc) if group.repeats == 1 \
+            else jax.lax.scan(layer, x, pc)
+    return lm_head(params, x, cfg, ctx), new
+
+
+@pytest.mark.parametrize("name,layers", [("stablelm-3b", None),
+                                         ("starcoder2-3b", None),
+                                         ("recurrentgemma-2b", 7)])
+@pytest.mark.parametrize("vector", [True, False], ids=["vector", "scalar"])
+def test_decode_step_in_place_write_matches_onehot_reference(name, layers,
+                                                             vector):
+    """The carried, in-place KV write (dense, windowed ring buffer, and a
+    scanned group mixing recurrent and attention blocks) gives the
+    reference's logits and caches over several steps, at positions that
+    include 0 and ``cache_len - 1`` (and wrap, when windowed); rows not
+    written keep their bytes."""
+    import dataclasses
+
+    from repro.core.plan import build_contexts
+    from repro.models.model import decode_step, init_cache, model_specs
+    from repro.models.params import init_params
+    cfg = _cfg(name)
+    if layers:
+        cfg = dataclasses.replace(cfg, num_layers=layers)
+    ctxs = build_contexts(cfg, None, _plan(cfg))
+    params = init_params(model_specs(cfg), jax.random.key(3))
+    B, S = 3, 16
+    leaves, tree = jax.tree.flatten(init_cache(cfg, B, S))
+    keys = jax.random.split(jax.random.key(4), len(leaves))
+    caches = jax.tree.unflatten(tree, [
+        jax.random.normal(k, c.shape, c.dtype) for k, c in zip(keys, leaves)])
+    L = min(S, cfg.window_size) if cfg.window_size else S  # cache_len
+    last = L + 2 if cfg.window_size else L - 1
+    if vector:           # row 0 from 0, row 2 through cache_len - 1
+        steps = [np.array([i, 5 + i, last - 4 + i]) for i in range(5)]
+    else:
+        steps = [np.int32(i) for i in (0, 1, L - 2, L - 1, last)]
+    ours = jax.jit(lambda c, t, p: decode_step(params, c, t, p, cfg, ctxs))
+    ref = jax.jit(lambda c, t, p: _onehot_decode_step(params, c, t, p, cfg))
+    rng = np.random.RandomState(5)
+    mine = theirs = caches
+    for pos in steps:
+        toks = jnp.asarray(rng.randint(0, cfg.vocab_size, (B,)), jnp.int32)
+        before = mine
+        lg, mine = ours(mine, toks, jnp.asarray(pos, jnp.int32))
+        lg_ref, theirs = ref(theirs, toks, jnp.asarray(pos, jnp.int32))
+        np.testing.assert_allclose(np.asarray(lg), np.asarray(lg_ref),
+                                   rtol=1e-5, atol=1e-5)
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5),
+            mine, theirs)
+        # the attention caches' unwritten rows are untouched, bit for bit
+        slot = np.broadcast_to(np.asarray(pos) % L, (B,))
+        kept = np.ones((B, L), bool)
+        kept[np.arange(B), slot] = False
+        for seg, g in mine.items():
+            for blk, c in g.items():
+                if set(c) != {"k", "v"}:
+                    continue
+                for n in ("k", "v"):
+                    now = np.asarray(c[n])
+                    was = np.asarray(before[seg][blk][n])
+                    np.testing.assert_array_equal(now[..., kept, :],
+                                                  was[..., kept, :])
+
+
 # --- spans, stamps and scopes -------------------------------------------------
 
 def _host_spans(run):
