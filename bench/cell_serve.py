@@ -13,9 +13,9 @@ completion's ``admitted_step``.
 The window runs whole waves, another one only while the mean wave so far
 still fits in ``--seconds``.  Afterwards a sample of the finished
 requests, drawn from the seed with the longest among them, is replayed
-through the plain float32 reference: the number compared is the widest
-gap by which a served (greedy) token's reference logit lies below the
-reference's best at that position.
+through the plain float32 reference the configuration names: the number
+compared is the widest gap by which a served (greedy) token's reference
+logit lies below the reference's best at that position.
 """
 from __future__ import annotations
 
@@ -28,8 +28,8 @@ from typing import Dict, List
 
 import numpy as np
 
+from bench import config as C
 from bench import flops, gen
-from bench import reference as ref
 
 
 class Timed:
@@ -75,7 +75,7 @@ def run(cell, config, seed, seconds, trace, devices, *, t0, clock, fault):
     from repro.models.params import init_params
     from repro.serve import engine as eng
 
-    mix, m = cell["mix"], config["model"]
+    mix, m = cell["mix"], C.model(config)
     cfg = arch_config(config)
     plan = Plan.load(str(cell["plan"]))
     cap, L, V = mix["capacity"], mix["cache_len"], m["vocab_size"]
@@ -163,7 +163,7 @@ def run(cell, config, seed, seconds, trace, devices, *, t0, clock, fault):
 
     # the reference over a sample of finished requests
     tr0 = time.perf_counter()
-    widest, n_checked = check(m, key, served, mix, seed)
+    widest, n_checked = check(config, key, served, mix, seed)
     ref_s = time.perf_counter() - tr0
     lim = cell["limits"]
     compared = {"gap": {"value": widest, "limit": lim["gap"]}}
@@ -223,13 +223,15 @@ def sample(served, want_tokens: int, seed: int):
     return pick, n
 
 
-def check(m, key, served, mix, seed, quant=None):
-    """Widest reference-logit gap of the served tokens of a sample.  With
-    ``quant``, the gap of the token the lower precision ranks first at
-    each of those positions instead (the control)."""
+def check(config, key, served, mix, seed, quant=None):
+    """Widest reference-logit gap of the served tokens of a sample, by the
+    reference the configuration names.  With ``quant``, the gap of the
+    token the lower precision ranks first at each of those positions
+    instead (the control)."""
     import jax
     import jax.numpy as jnp
 
+    ref, m = C.reference(config), C.model(config)
     L = mix["cache_len"]
     pick, n = sample(served, mix["check_tokens"], seed)
     params = jax.jit(lambda k: ref.init_params(m, k))(key)

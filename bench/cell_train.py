@@ -7,7 +7,7 @@ first steps (the window's own call and feed); the window continues the
 same object.  Each step's loss, the first gradient as the optimizer got
 it (its first moment after one step over ``1 - b1``) and the parameters'
 change after the check steps are compared, after the window, with the
-plain float32 reference run from the same seed.
+plain float32 reference the configuration names, run from the same seed.
 """
 from __future__ import annotations
 
@@ -21,8 +21,8 @@ from typing import Dict
 
 import numpy as np
 
+from bench import config as C
 from bench import flops, gen
-from bench import reference as ref
 
 ADAM_B1 = 0.9
 #: leaves whose reference gradient norm is under this share of the median
@@ -128,7 +128,7 @@ def run(cell, config, seed, seconds, trace, devices, *, t0, clock, fault):
     from bench.window import Window
     from repro.train.step import init_train_state, jit_train_step
 
-    mix, m = cell["mix"], config["model"]
+    mix, m = cell["mix"], C.model(config)
     cfg = arch_config(config)
     plan = _plan(cell, fault)
     B, S = mix["batch"], mix["seq"]
@@ -196,7 +196,7 @@ def run(cell, config, seed, seconds, trace, devices, *, t0, clock, fault):
 
     # the reference, after the program's state is freed
     tr0 = time.perf_counter()
-    r_losses, r_grads, r_change, r_norm0 = ref.train_steps(
+    r_losses, r_grads, r_change, r_norm0 = C.reference(config).train_steps(
         m, gen.jax_key(seed), check_batches, mix["hyper"],
         rows=mix["ref_rows"])
     got = compare(losses, grads, change, r_losses, r_grads, r_change)

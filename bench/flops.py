@@ -1,14 +1,16 @@
 """Operations and bytes the algorithms need, from a configuration's shapes.
 
-The benchmark's own arithmetic; it reads only the configuration file's
-``model`` dict.  Conventions:
+The benchmark's own arithmetic; it reads only the model dict
+(``bench/config.py`` ``model``) and each layer kind's counts in its block
+file (``bench/blocks/<kind>.py``).  Conventions:
 
 * A weight matrix of ``n`` parameters costs ``2 n`` operations per token
   forward (one multiply-add); training costs three times the forward
   (backward twice), and nothing recomputed is counted.
-* Causal attention at query position t (t keys) costs ``4 H D t``
-  (scores and the weighted sum).  A training sequence of S tokens
-  averages ``(S + 1) / 2`` keys per query.
+* A layer's weights are those it holds; a token multiplies its active
+  ones (all of them but in a layer of sparse experts).
+* Sequence mixing is each kind's own count at ``keys`` positions; a
+  training sequence of S tokens averages ``(S + 1) / 2`` keys per query.
 * The head is counted once per token that needs logits: every training
   token, every decoded token, and the last prompt token of a prefill.
 """
@@ -18,39 +20,9 @@ import json
 from pathlib import Path
 from typing import Dict, Iterable
 
+from bench import config as C
+
 BENCH = Path(__file__).resolve().parent
-
-
-def _kinds(m: Dict):
-    pat = list(m["block_pattern"])
-    reps, rem = divmod(m["num_layers"], len(pat))
-    return pat * reps + pat[:rem]
-
-
-def _hd(m: Dict) -> int:
-    return m["head_dim"] or m["d_model"] // m["num_heads"]
-
-
-def block_weights(kind: str, m: Dict) -> Dict[str, int]:
-    """{leaf: parameters} of one block's weight matrices, norms and
-    biases excluded."""
-    d, H = m["d_model"], m["num_heads"]
-    if kind == "attn":
-        KV, D, f = m["num_kv_heads"], _hd(m), m["d_ff"]
-        w = {"wq": d * H * D, "wk": d * KV * D, "wv": d * KV * D,
-             "wo": H * D * d, "wi": d * f, "wo_ffn": f * d}
-        if m["glu"]:
-            w["wg"] = d * f
-        return w
-    raise ValueError(f"no arithmetic for block kind {kind!r}")
-
-
-def block_other(kind: str, m: Dict) -> int:
-    """Parameters of a block's norms and biases."""
-    nrm = m["d_model"] * (2 if m["norm"] == "layernorm" else 1)
-    if kind == "attn":
-        return 2 * nrm
-    raise ValueError(kind)
 
 
 def param_count(m: Dict) -> int:
@@ -59,29 +31,27 @@ def param_count(m: Dict) -> int:
     n = V * d + d * (2 if m["norm"] == "layernorm" else 1)
     if not m["tie_embeddings"]:
         n += d * V
-    for kind in _kinds(m):
-        n += sum(block_weights(kind, m).values())
-        n += block_other(kind, m)
+    for kind in C.kinds(m):
+        b = C.block(kind)
+        n += b.held_params(m) + b.norm_params(m)
     return n
 
 
 def weight_params(m: Dict) -> int:
-    """Parameters that take part in a matmul for every token (the head
-    included, the embedding gather not)."""
+    """Parameters each token multiplies (the head included, the embedding
+    gather not)."""
     n = m["d_model"] * m["vocab_size"]
-    for kind in _kinds(m):
-        n += sum(block_weights(kind, m).values())
+    for kind in C.kinds(m):
+        n += C.block(kind).active_params(m)
     return n
 
 
 def _mix_per_token(m: Dict, keys: float) -> float:
     """Forward sequence-mixing operations of one token attending to
     ``keys`` positions, summed over layers."""
-    H = m["num_heads"]
     out = 0.0
-    for kind in _kinds(m):
-        if kind == "attn":
-            out += 4 * H * _hd(m) * keys
+    for kind in C.kinds(m):
+        out += C.block(kind).mix_flops(m, keys)
     return out
 
 

@@ -12,7 +12,10 @@ TPU), in float32, with weights upcast from the dtype they are stored in.
 float8_e4m3fn with a per-tensor scale, the next precision below the
 bfloat16 the configurations state.
 
-Block kind: ``attn`` (pre-norm attention + dense FFN).
+This module holds what every layer kind shares: the arithmetic, norms,
+rope, activations, the embedding and the head, the forward over the
+layer list, the loss, training and the gap.  Each kind's leaves and
+equations are its own file, ``bench/blocks/<kind>.py`` (``bench/config.py``).
 """
 from __future__ import annotations
 
@@ -24,6 +27,8 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from bench import config as C
+
 F32 = jnp.float32
 FP8_MAX = 448.0                    # largest finite float8_e4m3fn
 NORM_EPS = 1e-6
@@ -31,49 +36,13 @@ Z_LOSS = 1e-4
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.95, 1e-8
 
 
-# --- configuration -----------------------------------------------------------
-
-def stack_plan(m: Dict):
-    """[(pattern, repeats)]: the block pattern repeated over the depth,
-    with a partial last period unrolled."""
-    pat = tuple(m["block_pattern"])
-    reps, rem = divmod(m["num_layers"], len(pat))
-    out = []
-    if reps:
-        out.append((pat, reps))
-    if rem:
-        out.append((pat[:rem], 1))
-    return out
-
-
-def head_dim(m: Dict) -> int:
-    return m["head_dim"] or m["d_model"] // m["num_heads"]
-
-
 # --- parameter leaves: (shape, init, scale, dtype) ---------------------------
 
-def _norm(m, d):
+def norm_leaves(m, d):
     out = {"scale": ((d,), "ones", 1.0, m["dtype"])}
     if m["norm"] == "layernorm":
         out["bias"] = ((d,), "zeros", 1.0, m["dtype"])
     return out
-
-
-def _block_leaves(kind: str, m: Dict):
-    dt, d = m["dtype"], m["d_model"]
-    if kind == "attn":
-        H, KV, D, f = m["num_heads"], m["num_kv_heads"], head_dim(m), m["d_ff"]
-        ffn = {"wi": ((d, f), "normal", d ** -0.5, dt),
-               "wo": ((f, d), "normal", f ** -0.5, dt)}
-        if m["glu"]:
-            ffn["wg"] = ((d, f), "normal", d ** -0.5, dt)
-        return {"ln1": _norm(m, d),
-                "attn": {"wq": ((d, H, D), "normal", d ** -0.5, dt),
-                         "wk": ((d, KV, D), "normal", d ** -0.5, dt),
-                         "wv": ((d, KV, D), "normal", d ** -0.5, dt),
-                         "wo": ((H, D, d), "normal", (H * D) ** -0.5, dt)},
-                "ln2": _norm(m, d), "ffn": ffn}
-    raise ValueError(f"no reference for block kind {kind!r}")
 
 
 def _is_leaf(x) -> bool:
@@ -84,17 +53,17 @@ def param_leaves(m: Dict):
     """Nested dict of leaf descriptions, layers stacked per repeated group."""
     d, V, dt = m["d_model"], m["vocab_size"], m["dtype"]
     tree = {"embed": {"tok": ((V, d), "normal", 1.0, dt)}}
-    for gi, (pat, reps) in enumerate(stack_plan(m)):
+    for gi, (pat, reps) in enumerate(C.plan(m)):
         g = {}
         for j, kind in enumerate(pat):
-            leaves = _block_leaves(kind, m)
+            leaves = C.block(kind).leaves(m)
             if reps > 1:
                 leaves = jax.tree.map(
                     lambda l: ((reps,) + l[0],) + l[1:], leaves,
                     is_leaf=_is_leaf)
             g[f"b{j}"] = leaves
         tree[f"g{gi}"] = g
-    head = {"norm": _norm(m, d)}
+    head = {"norm": norm_leaves(m, d)}
     if not m["tie_embeddings"]:
         head["out"] = ((d, V), "normal", d ** -0.5, dt)
     tree["head"] = head
@@ -174,40 +143,14 @@ def act(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[name]
 
 
-def attn_block(p, x, m, ar: Arith):
-    S = x.shape[1]
-    pos = jnp.arange(S)
-    h = norm(p["ln1"], x, m["norm"])
-    a = p["attn"]
-    q = rope(ar.mm("bsd,dhk->bshk", h, a["wq"]), pos, m["rope"])
-    k = rope(ar.mm("bsd,dhk->bshk", h, a["wk"]), pos, m["rope"])
-    v = ar.mm("bsd,dhk->bshk", h, a["wv"])
-    B, _, H, D = q.shape
-    KV = k.shape[2]
-    q = q.reshape(B, S, KV, H // KV, D)
-    s = ar.mm("bqkgd,bskd->bkgqs", q, k) * D ** -0.5
-    s = jnp.where(pos[:, None] >= pos[None, :], s, -jnp.inf)
-    o = ar.mm("bkgqs,bskd->bqkgd", jax.nn.softmax(s, axis=-1), v)
-    x = x + ar.mm("bshk,hke->bse", o.reshape(B, S, H, D), a["wo"])
-    h = norm(p["ln2"], x, m["norm"])
-    f = p["ffn"]
-    u = ar.mm("bsd,df->bsf", h, f["wi"])
-    u = act(m["act"])(ar.mm("bsd,df->bsf", h, f["wg"])) * u if m["glu"] \
-        else act(m["act"])(u)
-    return x + ar.mm("bsf,fd->bsd", u, f["wo"])
-
-
-BLOCKS = {"attn": attn_block}
-
-
 def forward(params, tokens, m: Dict, ar: Arith, *, remat: bool = False):
     """tokens (B,S) int32 -> logits (B,S,V) float32."""
     x = params["embed"]["tok"][tokens].astype(F32)
-    for gi, (pat, reps) in enumerate(stack_plan(m)):
+    for gi, (pat, reps) in enumerate(C.plan(m)):
         def superblock(x, lp, pat=pat):
             for j, kind in enumerate(pat):
                 def fn(p, x, kind=kind):
-                    return BLOCKS[kind](p, x, m, ar)
+                    return C.block(kind).forward(p, x, m, ar)
                 if remat:
                     fn = jax.checkpoint(fn)
                 x = fn(lp[f"b{j}"], x)

@@ -7,8 +7,12 @@ mix (``bench/traffic/<mix>.json``, whose ``kind`` picks the train or the
 serve runner), and by its own name a plan (``bench/plans/<cell>.json``,
 loaded with ``Plan.load``) and the limits of its correctness check
 (``bench/limits/<cell>.json``).  Per-layer metrics are readers
-``bench/metrics/<metric>.py``.  Everything is found by name: a new cell,
-mix, configuration or metric is a new file.
+``bench/metrics/<metric>.py``.  A configuration names its plain reference
+module (``reference``) and, where its layers are not its ``block_pattern``
+alone, its ``stack``; each layer kind is ``bench/blocks/<kind>.py``, its
+leaves, equations and operation counts (``bench/config.py``).  Everything
+is found by name: a new cell, mix, configuration, layer kind or metric is
+a new file.
 
 Without a TPU, or with fewer chips than the cell asks for, it prints no
 result and exits 2.  The last line of stdout is one JSON object:

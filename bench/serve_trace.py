@@ -153,6 +153,7 @@ def serve_programs(model: Dict, mix: Dict, prompt_lens: Sequence[int] = (),
     import jax
     import jax.numpy as jnp
 
+    from bench import config as C
     from bench import gen
     from bench.run import arch_config, load_cell, load_json, ROOT
     from repro.core.plan import Plan
@@ -164,12 +165,12 @@ def serve_programs(model: Dict, mix: Dict, prompt_lens: Sequence[int] = (),
     cell = None
     for w in load_json(root / "BENCHMARK.json")["workloads"]:
         c = load_cell(w["name"], root)
-        if c["config"]["model"] == model and c["mix"] == mix:
+        if C.model(c["config"]) == model and c["mix"] == mix:
             cell = c
             break
     if cell is None:
         return None
-    cfg = arch_config({"model": model})
+    cfg = arch_config(cell["config"])
     cap, L = mix["capacity"], mix["cache_len"]
     sds = lambda t: jax.tree.map(  # noqa: E731
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), t)

@@ -14,7 +14,7 @@ import jax
 import pytest
 
 from bench import cell_serve, cell_train, gen
-from bench import reference as ref
+from bench import config as C
 from bench import run as R
 
 SEED = 2 ** 31 + 977
@@ -86,6 +86,8 @@ def test_train_control_fails_a_number(cell):
     toks, tgts = gen.train_pool(mix, R.arch_config({"model": m}), SEED)
     batches = [(toks[i], tgts[i]) for i in range(mix["check_steps"])]
     key = gen.jax_key(SEED)
+    ref = C.reference(cell["config"])
+    m = C.model(dict(cell["config"], model=m))
     want = ref.train_steps(m, key, batches, mix["hyper"])
     low = ref.train_steps(m, key, batches, mix["hyper"], quant="fp8")
     got = cell_train.compare(*low[:3], *want[:3])
@@ -98,6 +100,7 @@ def test_serve_control_fails_the_gap(cell):
     c, m = small(cell, "bfloat16")
     out = R.run_cell(c, SEED, 0.2, False, jax.devices()[:1],
                      t0=time.perf_counter(), model=m)
-    widest, _ = cell_serve.check(m, gen.jax_key(SEED), out["served"],
-                                 c["mix"], SEED, quant="fp8")
+    widest, _ = cell_serve.check(dict(c["config"], model=m),
+                                 gen.jax_key(SEED), out["served"], c["mix"],
+                                 SEED, quant="fp8")
     assert widest > c["limits"]["gap"], widest

@@ -6,15 +6,19 @@ from pathlib import Path
 import jax
 import pytest
 
+from bench import config as C
 from bench import flops
-from bench import reference as ref
 
 ROOT = Path(__file__).resolve().parents[2]
 CONFIGS = sorted((ROOT / "bench" / "configs").glob("*.json"))
 
 
+def config(path):
+    return json.loads(path.read_text())
+
+
 def model(path):
-    return json.loads(path.read_text())["model"]
+    return C.model(config(path))
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
@@ -22,10 +26,10 @@ def test_param_count_matches_the_program(path):
     from repro.models.model import model_specs
     from repro.models.params import abstract_params
     from bench.run import arch_config
-    m = model(path)
-    cfg = arch_config({"model": m})
+    cfg = arch_config(config(path))
     leaves = jax.tree.leaves(abstract_params(model_specs(cfg)))
-    assert flops.param_count(m) == sum(math.prod(x.shape) for x in leaves)
+    assert flops.param_count(model(path)) == sum(math.prod(x.shape)
+                                                 for x in leaves)
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
@@ -34,9 +38,9 @@ def test_reference_leaves_match_the_program(path):
     from repro.models.model import model_specs
     from repro.models.params import abstract_params
     from bench.run import arch_config
-    m = model(path)
-    prog = abstract_params(model_specs(arch_config({"model": m})))
-    mine = jax.tree.map(lambda l: (l[0], l[3]), ref.param_leaves(m),
+    prog = abstract_params(model_specs(arch_config(config(path))))
+    ref = C.reference(config(path))
+    mine = jax.tree.map(lambda l: (l[0], l[3]), ref.param_leaves(model(path)),
                         is_leaf=ref._is_leaf)
     got = jax.tree.map(lambda s: (tuple(s.shape), str(s.dtype)), prog)
     assert jax.tree_util.tree_structure(mine, is_leaf=lambda x: isinstance(

@@ -50,14 +50,14 @@ def main(argv=None) -> int:
     jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from bench import cell_serve, cell_train, gen
-    from bench import reference as ref
+    from bench import config as C
     from bench.run import arch_config, load_cell, run_cell
 
     cell = load_cell(args.cell)
     cell["limits"] = {k: float("inf") for k in cell["limits"]}
     dev = jax.devices()[:cell["chips"]]
     kind = cell["mix"]["kind"]
-    m = cell["config"]["model"]
+    ref, m = C.reference(cell["config"]), C.model(cell["config"])
 
     def emit(what, seed, numbers, **kw):
         print(json.dumps({"reading": what, "seed": seed, **numbers, **kw}),
@@ -97,8 +97,9 @@ def main(argv=None) -> int:
             emit("control:fp8", seed, cell_train.compare(*pair),
                  notes=cell_train.detail(*pair))
         else:
-            widest, n = cell_serve.check(m, gen.jax_key(seed), out["served"],
-                                         cell["mix"], seed, quant="fp8")
+            widest, n = cell_serve.check(cell["config"], gen.jax_key(seed),
+                                         out["served"], cell["mix"], seed,
+                                         quant="fp8")
             emit("control:fp8", seed, {"gap": widest}, tokens=n)
     return 0
 
